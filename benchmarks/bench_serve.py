@@ -121,7 +121,6 @@ def _replay(cfg, params, trace: list[dict], max_slots: int = MAX_SLOTS,
     paged = cfg.family == "dense"
     engine = ServeEngine(cfg, params, max_slots=max_slots, max_len=MAX_LEN,
                          page=PAGE if paged else None,
-                         interpret=True if paged else None,
                          batched=batched)
     # warm-up replay: pays every trace/compile once so the measured pass
     # is warm steady-state serving for EVERY row — without it, a row

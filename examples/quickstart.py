@@ -42,10 +42,10 @@ print("v5e bf16 4096^3:", bc.as_tuple(), f"VMEM {bc.vmem_bytes // 2**20}MiB",
 k1, k2 = jax.random.split(jax.random.PRNGKey(0))
 A = jax.random.normal(k1, (256, 192), jnp.float32)
 B = jax.random.normal(k2, (192, 128), jnp.float32)
-C = ops.moa_gemm(A, B, interpret=True)
+C = ops.moa_gemm(A, B)
 err = float(jnp.max(jnp.abs(C - ref.gemm_ref(A, B))))
 print(f"\nPallas MoA GEMM vs oracle: max err {err:.2e}")
-K = ops.kron(jnp.eye(2, dtype=jnp.float32), A[:4, :4], interpret=True)
+K = ops.kron(jnp.eye(2, dtype=jnp.float32), A[:4, :4])
 print("ipophp kron through the same circuit:", K.shape)
 
 # -- 4. a tiny assigned arch ------------------------------------------------

@@ -49,6 +49,17 @@ class HardwareEntry:
         """Whether Pallas kernels should run in interpret mode here."""
         return self.backend != "pallas"
 
+    def xla_options(self) -> dict[str, str]:
+        """XLA compiler options for a program that calls this entry's
+        kernels.  On a TPU, XLA's own scoped VMEM limit (16 MiB on v5e)
+        also bounds a kernel that XLA fuses its neighbours into (a
+        scanned layer stack's gradient update, say), so it is raised to
+        the capacity the schedules were certified under."""
+        if self.backend != "pallas" or not self.name.startswith("tpu"):
+            return {}
+        kib = self.shape.vmem.capacity_bytes // 1024
+        return {"xla_tpu_scoped_vmem_limit_kib": str(kib)}
+
 
 _REGISTRY: dict[str, HardwareEntry] = {}
 
@@ -92,15 +103,31 @@ CPU_ENTRY = register_hardware(HardwareEntry(
     "cpu", TPU_V5E, "interpret", "host CPU; v5e schedules via Pallas interpreter"))
 
 
+#: TPU ``device_kind`` (as JAX reports it) -> registry entry.  A TPU that
+#: is not listed has no measured table here, so detection refuses it
+#: instead of compiling another chip's schedules for it.
+TPU_KINDS = {"TPU v5 lite": "tpu_v5e"}
+
+
+def entry_for_device(platform: str, device_kind: str) -> str:
+    """The registry entry for a device of ``platform``/``device_kind``."""
+    if platform == "tpu":
+        try:
+            return TPU_KINDS[device_kind]
+        except KeyError:
+            raise RuntimeError(
+                f"no hardware entry for TPU device_kind {device_kind!r}; "
+                f"known: {sorted(TPU_KINDS)}") from None
+    if platform == "gpu":
+        return "v100"
+    return "cpu"
+
+
 @lru_cache(maxsize=1)
 def _detected_name() -> str:
     import jax
-    backend = jax.default_backend()
-    if backend == "tpu":
-        return "tpu_v5e"
-    if backend == "gpu":
-        return "v100"
-    return "cpu"
+    dev = jax.devices()[0]
+    return entry_for_device(dev.platform, dev.device_kind)
 
 
 _OVERRIDE: Optional[str] = None

@@ -73,13 +73,13 @@ class HardwareShape:
         return tuple(s for _, s in self.mesh_axes)
 
 
-# TPU v5e, per task statement: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-# ICI.  VMEM ~128 MiB on v5e? (v5e has 128MB? v4: 128MiB? ) -- v5e VMEM is
-# 128 MiB total? Public spec: TPU v5e has 16 GiB HBM @819GBps and ~100 MiB
-# on-chip VMEM is not published; we adopt 64 MiB usable VMEM budget per core
-# half of which we leave for double-buffering headroom.  The *solver* takes
-# the budget as a parameter so this constant is not load-bearing for
-# correctness, only for default block choices.
+# TPU v5e (Google Cloud "TPU v5e" page): 197 TFLOP/s bf16, 16 GiB HBM at
+# 819 GB/s, ~50 GB/s per ICI link.  VMEM is 128 MiB per TensorCore (JAX's
+# own ``pallas.mosaic.tpu_info`` table for "TPU v5 lite").  The table
+# exposes half of it as the capacity: the block solvers budget fractions of
+# this number, the derivation certifies every working set under it, and
+# ``emit.compiler_params`` raises Mosaic's scoped VMEM limit (16 MiB by
+# default on v5e) to it, which leaves the other half to the compiler.
 TPU_V5E = HardwareShape(
     name="tpu_v5e",
     mesh_axes=(("data", 16), ("model", 16)),

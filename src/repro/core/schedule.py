@@ -691,6 +691,9 @@ class ScheduleBundle:
     out_shape: tuple[int, ...] = ()
     in_shapes: tuple[tuple[int, ...], ...] = ()
     acc_dtype: str = "float32"       # accumulation dtype the emitter honors
+    #: the hardware table's VMEM capacity — the bound the working set was
+    #: certified under, handed to the compiler as its scoped VMEM limit
+    vmem_limit_bytes: Optional[int] = None
 
 
 def bundle_needs_padding(bundle: ScheduleBundle) -> bool:
@@ -815,7 +818,8 @@ def _build_bundle(nf: "expr_mod.NormalForm", dtype, hw_shape,
                           derive_schedule(lifted, hw_shape, dtype, acc_dtype),
                           blocks, logical, padded,
                           nf.out_shape(), nf.leaf_storage_shapes(),
-                          acc_dtype=acc_dtype)
+                          acc_dtype=acc_dtype,
+                          vmem_limit_bytes=hw_shape.vmem.capacity_bytes)
 
 
 def _build_recurrent_bundle(rf: "expr_mod.RecurrentForm", dtype, hw_shape,
@@ -943,7 +947,8 @@ def _build_recurrent_bundle(rf: "expr_mod.RecurrentForm", dtype, hw_shape,
     in_shapes += tuple(l.storage_shape() for l in rf.aux)
     return ScheduleBundle(rf.name, sched, blocks, logical, padded,
                           rf.stages[-1].out_shape(), in_shapes,
-                          acc_dtype=acc_dtype)
+                          acc_dtype=acc_dtype,
+                          vmem_limit_bytes=hw_shape.vmem.capacity_bytes)
 
 
 def _page_schedule(sched: RecurrentSchedule, rf: "expr_mod.RecurrentForm",

@@ -35,21 +35,12 @@ from repro.distributed import plan as dplan
 from repro.kernels import ops
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static ring size: jax.lax.axis_size where it exists, else the classic
-    psum(1) idiom (constant-folded to a Python int on older jax)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def ag_matmul(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
     """Inside shard_map: x (m_shard, k) sharded on rows over ``axis_name``;
     w (k, n) replicated.  Returns y = all_gather(x) @ w, (m_full, n),
     computed as a ppermute ring (no full gather buffer) — the ring being
     the latency-hiding form of the plan's derived all-gather."""
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m_shard, kdim = x.shape
     n = w.shape[1]
@@ -78,7 +69,7 @@ def psum_matmul(x: jax.Array, w: jax.Array, axis_name: str) -> jax.Array:
     row-sharded over ``axis_name``.  Returns the *full* y = sum_p x_p @ w_p
     on every device, with the derived psum pipelined as chunked per-row-block
     reductions so transfers overlap the remaining chunks' matmuls."""
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     m, k_shard = x.shape
     plan = dplan.matmul_plan(m, k_shard * p, w.shape[1],
                              MeshShape(((axis_name, p),)),

@@ -124,19 +124,6 @@ def constrain(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``: new jax exposes ``jax.shard_map``
-    (``check_vma``); older releases ship ``jax.experimental.shard_map``
-    (``check_rep``).  All in-repo callers go through here."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
-
 def _current_mesh() -> Optional[Mesh]:
     try:
         env = jax._src.mesh.thread_resources.env  # physical mesh ctx manager
@@ -148,8 +135,7 @@ def _current_mesh() -> Optional[Mesh]:
     try:
         am = jax.sharding.get_abstract_mesh()
         if am is not None and not am.empty:  # use_mesh-style context
-            cm = getattr(jax._src.mesh, "get_concrete_mesh", lambda: None)()
-            return cm
+            return jax._src.mesh.get_concrete_mesh()
     except Exception:
         pass
     return None

@@ -43,14 +43,16 @@ from repro.core import schedule as sched_mod
 from repro.core.schedule import Schedule, ScheduleBundle, StreamingSchedule
 from repro.core.semiring import MASK_NEG_INF as NEG_INF
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# kernels run on every jax this repo targets.
-_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-
-def compiler_params(*, dimension_semantics) -> object:
-    return _PARAMS_CLS(dimension_semantics=tuple(dimension_semantics))
+def compiler_params(*, dimension_semantics,
+                    vmem_limit_bytes: Optional[int] = None
+                    ) -> pltpu.CompilerParams:
+    """Mosaic parameters for one kernel.  ``vmem_limit_bytes`` is the VMEM
+    capacity of the hardware table the schedule was derived against: the
+    derivation certifies the working set under it, so the compiler's
+    scoped limit (16 MiB by default on v5e) must not be lower."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics),
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 #: largest page table ``_index_map`` will lower.  The per-page slab lookup
@@ -118,6 +120,28 @@ def _index_map(grid_dims: tuple[Optional[int], ...],
     return imap
 
 
+def _block_origin(spec) -> tuple:
+    """Element index of the current grid cell's block origin in ``spec``'s
+    operand — what its BlockSpec index map would have pinned."""
+    return tuple((pl.program_id(d) if d is not None else 0) * b + off * b
+                 for d, b, off in zip(spec.grid_dims, spec.block,
+                                      spec.offsets or (0,) * len(spec.block)))
+
+
+def _in_spec(spec, scalar: bool = False) -> pl.BlockSpec:
+    """The BlockSpec of one input operand.  A ``scalar`` operand (the int32
+    position of a ``dynamic-pos`` kind, read only as scalars that steer
+    control flow) rides whole in SMEM: its per-cell block, e.g. ``(1, 2)``
+    of a ``(slots, 2)`` array, breaks the (8, 128) VMEM tiling rule, and
+    scalar loads belong in SMEM anyway.  The body reads it at
+    ``_block_origin``."""
+    if scalar:
+        return pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.BlockSpec(spec.block, _index_map(spec.grid_dims, spec.offsets,
+                                               spec.page_table,
+                                               spec.page_slot_dim))
+
+
 def _jnp_combine(name: str) -> Callable:
     return getattr(jnp, semiring.combine_def(name).jnp_name)
 
@@ -147,7 +171,8 @@ def _general_combine(schedule: Schedule, combine_fn, reducer, vals):
 
 def emit_pallas(schedule: Schedule, combine=None, *, out_dtype=None,
                 interpret: bool = False,
-                acc_dtype=None) -> Callable:
+                acc_dtype=None,
+                vmem_limit_bytes: Optional[int] = None) -> Callable:
     """Build the ``pl.pallas_call`` a schedule describes.
 
     Returns ``fn(*operands) -> out`` over arrays of exactly the schedule's
@@ -157,6 +182,7 @@ def emit_pallas(schedule: Schedule, combine=None, *, out_dtype=None,
     accumulator the solver budgeted for — it becomes the MXU
     ``preferred_element_type`` and the sigma scratch dtype; only the
     (mul, add) semiring has non-f32 accumulation paths.
+    ``vmem_limit_bytes`` rides to ``compiler_params``.
     """
     ni = len(schedule.ins)
     out_dtype = jnp.dtype(out_dtype or jnp.float32)
@@ -226,7 +252,8 @@ def emit_pallas(schedule: Schedule, combine=None, *, out_dtype=None,
         scratch_shapes=([pltpu.VMEM(out_block, acc_dtype)]
                         if red is not None else []),
         compiler_params=compiler_params(
-            dimension_semantics=schedule.dimension_semantics),
+            dimension_semantics=schedule.dimension_semantics,
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )
 
@@ -389,6 +416,22 @@ def _softmax_kind(rs: StreamingSchedule, *, scale, causal, logical_stream,
     return body, scratch
 
 
+def _tril(q: int) -> jax.Array:
+    """The (q, q) causal mask ``i >= j`` of a chunk.  Contracted against
+    it on the MXU (as 0/1 values), a row gives its prefix or suffix sums:
+    Mosaic has no ``cumsum`` lowering, so the SSD segment sums run so."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >=
+            jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
+
+
+def _dot(a: jax.Array, b: jax.Array, contract) -> jax.Array:
+    """2-D ``dot_general`` contracting dims ``contract = (a_dims, b_dims)``
+    at full f32 precision (the recurrences carry f32 state)."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=a.dtype)
+
+
 def _ssd_kind(rs: StreamingSchedule, *, scale, causal, logical_stream,
               out_dtype, acc_dtype):
     """The SSD (Mamba-2) monoid: one inter-chunk state ``h`` (head,
@@ -403,7 +446,6 @@ def _ssd_kind(rs: StreamingSchedule, *, scale, causal, logical_stream,
     stream_dim = rs.stream_grid_dim
     nk = rs.grid[stream_dim].extent
     scores_plan, _ = rs.stages[0].einsum_plan()         # "in,jn->ij"
-    ctx_plan, _ = rs.stages[1].einsum_plan()            # "hij,jhp->ihp"
     c_cell = _cell_shape(rs.ins[0])                     # (q, n)
     b_cell = _cell_shape(rs.ins[1])                     # (q, n)
     x_cell = _cell_shape(rs.ins[2])                     # (q, h, p)
@@ -412,49 +454,66 @@ def _ssd_kind(rs: StreamingSchedule, *, scale, causal, logical_stream,
     q = da_cell[0]
     n_so = len(rs.state_outs)         # 1 (h only) or 2 (+ per-chunk h_in)
 
+    x_lead = (0,) * (len(rs.ins[2].block) - len(x_cell))
+    y_lead = (0,) * (len(rs.out.block) - len(x_cell))
+    hdim, p, n = h_cell
+
     def body(*refs):
         y_ref, hf_ref = refs[ni], refs[ni + 1]
-        h_ref = refs[ni + 1 + n_so]
+        h_ref, dat_ref = refs[ni + 1 + n_so:ni + 3 + n_so]
         ki = pl.program_id(stream_dim)
 
         @pl.when(ki == 0)
         def _init():
             h_ref[...] = refs[4][...].reshape(h_cell).astype(acc_dtype)
 
+        if n_so == 2:                 # checkpoint the state entering ki
+            refs[ni + 2][...] = h_ref[...].reshape(rs.state_outs[1].block)
         Cb = refs[0][...].reshape(c_cell).astype(acc_dtype)
         Bb = refs[1][...].reshape(b_cell).astype(acc_dtype)
-        Xb = refs[2][...].reshape(x_cell).astype(acc_dtype)
-        dAb = refs[3][...].reshape(da_cell).astype(acc_dtype)
-        h_prev = h_ref[...]
-        if n_so == 2:                 # checkpoint the state entering ki
-            refs[ni + 2][...] = h_prev.reshape(rs.state_outs[1].block)
-        csh = jnp.transpose(jnp.cumsum(dAb, axis=0))        # (h, i)
-        seg = csh[:, :, None] - csh[:, None, :]             # (h, i, j)
-        tril = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
-            jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-        L = jnp.exp(jnp.where(tril[None], seg, NEG_INF))    # (h, i, j)
+        # the decay head-major, so the loop below reads one head's row
+        dat_ref[...] = jnp.transpose(
+            refs[3][...].reshape(da_cell).astype(acc_dtype))    # (h, j)
+        tril = _tril(q)
+        tril_f = jnp.where(tril, jnp.ones((), acc_dtype),
+                           jnp.zeros((), acc_dtype))
+        ones_n = jnp.ones((q, n), acc_dtype)
         G = jnp.einsum(scores_plan, Cb, Bb,
                        preferred_element_type=acc_dtype)    # (i, j)
-        P = G[None] * L                                     # (h, i, j)
-        y = jnp.einsum(ctx_plan, P, Xb,
-                       preferred_element_type=acc_dtype)    # (i, h, p)
-        in_decay = jnp.exp(csh)                             # (h, i)
-        t_off = jnp.einsum("in,hpn->ihp", Cb, h_prev,
-                           preferred_element_type=acc_dtype)
-        y = y + t_off * jnp.transpose(in_decay)[:, :, None]
-        y_ref[...] = y.astype(out_dtype).reshape(rs.out.block)
-        total = csh[:, -1]                                  # (h,)
-        decay_states = jnp.exp(total[:, None] - csh)        # (h, j)
-        Xd = Xb * jnp.transpose(decay_states)[:, :, None]   # (j, h, p)
-        S = jnp.einsum("jn,jhp->hpn", Bb, Xd,
-                       preferred_element_type=acc_dtype)
-        h_ref[...] = jnp.exp(total)[:, None, None] * h_prev + S
+
+        # the context stage and the state update run per head: each is a
+        # 2-D MXU contraction over (q, p) / (p, n) tiles, where the fused
+        # (h, i, j) form needs 3-D relayouts Mosaic cannot lower.  A loop
+        # (not an unrolled one) keeps one head's (q, q) tiles live at once
+        def head(hh, carry):
+            da = dat_ref[pl.ds(hh, 1), :]                   # (1, j)
+            csh = _dot(tril_f, da, ((1,), (1,)))            # (i, 1)
+            csh_row = _dot(da, tril_f, ((1,), (1,)))        # (1, i)
+            L = jnp.exp(jnp.where(tril, csh - csh_row, NEG_INF))
+            xh = refs[2][x_lead + (slice(None), pl.ds(hh, 1), slice(None))
+                         ].reshape(q, p).astype(acc_dtype)  # (j, p)
+            h_prev = h_ref[hh]                              # (p, n)
+            y = _dot(G * L, xh, ((1,), (0,)))               # (i, p)
+            y = y + _dot(Cb, h_prev, ((1,), (1,))) * jnp.exp(csh)
+            y_ref[y_lead + (slice(None), pl.ds(hh, 1), slice(None))] = \
+                y.astype(out_dtype).reshape(q, 1, p)
+            total = csh[q - 1:q]                            # (1, 1)
+            xd = xh * jnp.exp(total - csh)                  # (j, p)
+            # the chunk decay as a (1, n) row (the same sum, contracted
+            # against ones): Mosaic cannot broadcast (1, 1) to (p, n)
+            total_row = _dot(da, ones_n, ((1,), (0,)))      # (1, n)
+            h_ref[hh] = (jnp.exp(total_row) * h_prev
+                         + _dot(xd, Bb, ((0,), (0,))))      # (p, n)
+            return carry
+
+        jax.lax.fori_loop(0, hdim, head, 0)
 
         @pl.when(ki == nk - 1)
         def _flush():
             hf_ref[...] = h_ref[...].reshape(rs.state_outs[0].block)
 
-    scratch = [pltpu.VMEM(h_cell, acc_dtype)]
+    scratch = [pltpu.VMEM(h_cell, acc_dtype),
+               pltpu.VMEM((hdim, q), acc_dtype)]
     return body, scratch
 
 
@@ -719,8 +778,9 @@ def _ssd_backward_kind(rs: StreamingSchedule, *, scale, causal,
     """The SSD backward monoid over *reversed* chunks (the ops layer flips
     the chunk axis): the carried state is the inter-chunk cotangent ``dh``,
     seeded from the final-state cotangent ``dHf`` at step 0.  Each streamed
-    step replays the forward chunk factoring — same einsums, same order —
-    from the saved state checkpoint ``Hin``, then chains every cotangent:
+    step replays the forward chunk factoring per head, as the forward
+    runs it, from the saved state checkpoint ``Hin``, then chains every
+    cotangent:
     ``dX`` is the main output, ``dB``/``dC``/``ddA`` export per step,
     ``dh`` steps backward and flushes as ``dh0``.  Operand order:
     (C, B, dY, X, dA, Hin, dHf); outputs (dX, dh0, dB, dC, ddA)."""
@@ -728,19 +788,22 @@ def _ssd_backward_kind(rs: StreamingSchedule, *, scale, causal,
     stream_dim = rs.stream_grid_dim
     nk = rs.grid[stream_dim].extent
     scores_plan, _ = rs.stages[0].einsum_plan()         # "in,jn->ij"
-    ctx_plan, _ = rs.stages[1].einsum_plan()            # "hij,ihp->jhp"
     c_cell = _cell_shape(rs.ins[0])                     # (q, n)
     b_cell = _cell_shape(rs.ins[1])                     # (q, n)
-    dy_cell = _cell_shape(rs.ins[2])                    # (q, h, p)
     x_cell = _cell_shape(rs.ins[3])                     # (q, h, p)
     da_cell = _cell_shape(rs.ins[4])                    # (q, h)
     h_cell = _cell_shape(rs.ins[5])                     # (h, p, n)
     q, hdim = da_cell
+    p, n = h_cell[1:]
+    dy_lead = (0,) * (len(rs.ins[2].block) - len(x_cell))
+    x_lead = (0,) * (len(rs.ins[3].block) - len(x_cell))
+    h_lead = (0,) * (len(rs.ins[5].block) - len(h_cell))
+    dx_lead = (0,) * (len(rs.out.block) - len(x_cell))
 
     def body(*refs):
         dx_ref = refs[ni]
         dh0_ref, db_ref, dc_ref, dda_ref = refs[ni + 1:ni + 5]
-        dh_ref = refs[ni + 5]
+        dh_ref, dat_ref, ddat_ref = refs[ni + 5:ni + 8]
         ki = pl.program_id(stream_dim)
 
         @pl.when(ki == 0)
@@ -749,77 +812,86 @@ def _ssd_backward_kind(rs: StreamingSchedule, *, scale, causal,
 
         Cb = refs[0][...].reshape(c_cell).astype(acc_dtype)
         Bb = refs[1][...].reshape(b_cell).astype(acc_dtype)
-        dYb = refs[2][...].reshape(dy_cell).astype(acc_dtype)
-        Xb = refs[3][...].reshape(x_cell).astype(acc_dtype)
-        dAb = refs[4][...].reshape(da_cell).astype(acc_dtype)
-        Hc = refs[5][...].reshape(h_cell).astype(acc_dtype)
-        dh = dh_ref[...]
-
-        # replay the forward chunk factoring (identical order of ops)
-        csh = jnp.transpose(jnp.cumsum(dAb, axis=0))        # (h, i)
-        seg = csh[:, :, None] - csh[:, None, :]
-        tril = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
-            jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-        L = jnp.exp(jnp.where(tril[None], seg, NEG_INF))    # (h, i, j)
+        dat_ref[...] = jnp.transpose(
+            refs[4][...].reshape(da_cell).astype(acc_dtype))    # (h, j)
+        tril = _tril(q)
+        tril_f = jnp.where(tril, jnp.ones((), acc_dtype),
+                           jnp.zeros((), acc_dtype))
+        ones_n = jnp.ones((q, n), acc_dtype)
+        ones_q = jnp.ones((q, 1), acc_dtype)
+        last = jax.lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
         G = jnp.einsum(scores_plan, Cb, Bb,
-                       preferred_element_type=acc_dtype)
-        P = G[None] * L
-        in_decay = jnp.exp(csh)                             # (h, i)
-        t_off = jnp.einsum("in,hpn->ihp", Cb, Hc,
-                           preferred_element_type=acc_dtype)
-        total = csh[:, -1]                                  # (h,)
-        decay_states = jnp.exp(total[:, None] - csh)        # (h, j)
-        Xd = Xb * jnp.transpose(decay_states)[:, :, None]   # (j, h, p)
+                       preferred_element_type=acc_dtype)    # (i, j)
 
-        # chain the cotangents back through the factoring
-        dtotal = jnp.einsum("hpn,hpn->h", dh, Hc,
-                            preferred_element_type=acc_dtype) * \
-            jnp.exp(total)
-        dh_prev = jnp.exp(total)[:, None, None] * dh
-        dBb = jnp.einsum("hpn,jhp->jn", dh, Xd,
-                         preferred_element_type=acc_dtype)
-        dXd = jnp.einsum("jn,hpn->jhp", Bb, dh,
-                         preferred_element_type=acc_dtype)
-        dXb = dXd * jnp.transpose(decay_states)[:, :, None]
-        ddec = jnp.einsum("jhp,jhp->hj", dXd, Xb,
-                          preferred_element_type=acc_dtype)
-        dtotal = dtotal + jnp.sum(ddec * decay_states, axis=1)
-        dcsh = -(ddec * decay_states)                       # (h, j)
-        dt_off = dYb * jnp.transpose(in_decay)[:, :, None]  # (i, h, p)
-        din_decay = jnp.transpose(jnp.sum(dYb * t_off, axis=-1))  # (h, i)
-        dcsh = dcsh + din_decay * in_decay
-        dCb = jnp.einsum("ihp,hpn->in", dt_off, Hc,
-                         preferred_element_type=acc_dtype)
-        dh_prev = dh_prev + jnp.einsum("in,ihp->hpn", Cb, dt_off,
-                                       preferred_element_type=acc_dtype)
-        dP = jnp.einsum("ihp,jhp->hij", dYb, Xb,
-                        preferred_element_type=acc_dtype)
-        dXb = dXb + jnp.einsum(ctx_plan, P, dYb,
-                               preferred_element_type=acc_dtype)
-        dG = jnp.sum(dP * L, axis=0)                        # (i, j)
-        dL = dP * G[None]
-        dseg = jnp.where(tril[None], dL * L, 0.0)
-        dcsh = dcsh + dseg.sum(axis=2) - dseg.sum(axis=1)
-        dCb = dCb + jnp.einsum("ij,jn->in", dG, Bb,
-                               preferred_element_type=acc_dtype)
-        dBb = dBb + jnp.einsum("ij,in->jn", dG, Cb,
-                               preferred_element_type=acc_dtype)
-        last = jax.lax.broadcasted_iota(jnp.int32, (hdim, q), 1) == q - 1
-        dcsh = dcsh + jnp.where(last, dtotal[:, None], 0.0)
-        ddAb = jnp.transpose(jnp.flip(
-            jnp.cumsum(jnp.flip(dcsh, axis=1), axis=1), axis=1))   # (j, h)
+        def mid(ref, lead, hh):                             # (q, p) of head
+            return ref[lead + (slice(None), pl.ds(hh, 1), slice(None))
+                       ].reshape(q, p).astype(acc_dtype)
 
-        dx_ref[...] = dXb.astype(out_dtype).reshape(rs.out.block)
-        db_ref[...] = dBb.reshape(rs.state_outs[1].block)
-        dc_ref[...] = dCb.reshape(rs.state_outs[2].block)
-        dda_ref[...] = ddAb.reshape(rs.state_outs[3].block)
-        dh_ref[...] = dh_prev
+        # per head, as the forward: replay the chunk factoring, then chain
+        # the cotangents back through it; dB, dC and dG sum over heads
+        def head(hh, carry):
+            dB, dC, dG = carry
+            da = dat_ref[pl.ds(hh, 1), :]                   # (1, j)
+            csh = _dot(tril_f, da, ((1,), (1,)))            # (i, 1)
+            csh_row = _dot(da, tril_f, ((1,), (1,)))        # (1, i)
+            L = jnp.exp(jnp.where(tril, csh - csh_row, NEG_INF))
+            P = G * L
+            in_decay = jnp.exp(csh)                         # (i, 1)
+            hc = refs[5][h_lead + (hh,)].astype(acc_dtype)  # (p, n)
+            dh = dh_ref[hh]                                 # (p, n)
+            t_off = _dot(Cb, hc, ((1,), (1,)))              # (i, p)
+            total = csh[q - 1:q]                            # (1, 1)
+            total_row = _dot(da, ones_n, ((1,), (0,)))      # (1, n)
+            decay = jnp.exp(total - csh)                    # (j, 1)
+            xh = mid(refs[3], x_lead, hh)                   # (j, p)
+            dyh = mid(refs[2], dy_lead, hh)                 # (i, p)
+            xd = xh * decay
+
+            dtotal = jnp.sum(jnp.sum(dh * hc, axis=1, keepdims=True),
+                             axis=0, keepdims=True) * jnp.exp(total)
+            dh_prev = jnp.exp(total_row) * dh
+            dB = dB + _dot(xd, dh, ((1,), (0,)))            # (j, n)
+            dxd = _dot(Bb, dh, ((1,), (1,)))                # (j, p)
+            dx = dxd * decay
+            ddec = jnp.sum(dxd * xh, axis=1, keepdims=True)     # (j, 1)
+            dtotal = dtotal + jnp.sum(ddec * decay, axis=0, keepdims=True)
+            dcsh = -(ddec * decay)
+            dt_off = dyh * in_decay                         # (i, p)
+            dcsh = dcsh + jnp.sum(dyh * t_off, axis=1,
+                                  keepdims=True) * in_decay
+            dC = dC + _dot(dt_off, hc, ((1,), (0,)))        # (i, n)
+            dh_prev = dh_prev + _dot(dt_off, Cb, ((0,), (0,)))
+            dP = _dot(dyh, xh, ((1,), (1,)))                # (i, j)
+            dx = dx + _dot(P, dyh, ((0,), (0,)))            # (j, p)
+            dG = dG + dP * L
+            dseg = jnp.where(tril, dP * G * L, 0.0)
+            dcsh = (dcsh + jnp.sum(dseg, axis=1, keepdims=True)
+                    - _dot(dseg, ones_q, ((0,), (0,))))     # (j, 1)
+            dcsh = dcsh + jnp.where(last, dtotal, 0.0)
+            # the suffix sum over i >= j, as a row: dcsh against the mask
+            ddat_ref[pl.ds(hh, 1), :] = _dot(dcsh, tril_f, ((0,), (0,)))
+            dx_ref[dx_lead + (slice(None), pl.ds(hh, 1), slice(None))] = \
+                dx.astype(out_dtype).reshape(q, 1, p)
+            dh_ref[hh] = dh_prev
+            return dB, dC, dG
+
+        zeros_qn = jnp.zeros((q, n), acc_dtype)
+        dB, dC, dG = jax.lax.fori_loop(
+            0, hdim, head, (zeros_qn, zeros_qn, jnp.zeros((q, q), acc_dtype)))
+        dC = dC + _dot(dG, Bb, ((1,), (0,)))
+        dB = dB + _dot(dG, Cb, ((0,), (0,)))
+        db_ref[...] = dB.reshape(rs.state_outs[1].block)
+        dc_ref[...] = dC.reshape(rs.state_outs[2].block)
+        dda_ref[...] = jnp.transpose(ddat_ref[...]).reshape(
+            rs.state_outs[3].block)
 
         @pl.when(ki == nk - 1)
         def _flush():
             dh0_ref[...] = dh_ref[...].reshape(rs.state_outs[0].block)
 
-    scratch = [pltpu.VMEM(h_cell, acc_dtype)]
+    scratch = [pltpu.VMEM(h_cell, acc_dtype),
+               pltpu.VMEM((hdim, q), acc_dtype),
+               pltpu.VMEM((hdim, q), acc_dtype)]
     return body, scratch
 
 
@@ -846,6 +918,7 @@ def _windowed_decode_kind(rs: StreamingSchedule, *, scale, causal,
     if rs.prefix_len:
         raise ValueError("windowed_decode does not take a prefix_len — "
                          "prefix tokens are all at or before the query")
+    pos_spec = rs.ins[KIND_CONTRACTS["windowed_decode"].pos_input]
     scores_plan, scores_keep = rs.stages[0].einsum_plan()
     ctx_plan, ctx_keep = rs.stages[1].einsum_plan()
     acc_block = rs.acc_block
@@ -854,7 +927,9 @@ def _windowed_decode_kind(rs: StreamingSchedule, *, scale, causal,
         o_ref = refs[ni]
         m_ref, l_ref, acc_ref = refs[ni + 1:ni + 4]
         ki = pl.program_id(stream_dim)
-        vpos = refs[ni - 1][0, 0]          # view-relative query position
+        # view-relative query position: POS rides whole in SMEM, read at
+        # the origin of the block its BlockSpec would have pinned
+        vpos = refs[ni - 1][_block_origin(pos_spec)]
 
         @pl.when(ki == 0)
         def _init():
@@ -984,7 +1059,8 @@ def register_recurrence_kind(kind: str, builder: Callable,
 def emit_recurrent(rs: StreamingSchedule, *, scale: float = 1.0,
                    causal: bool = False, logical_stream: Optional[int] = None,
                    out_dtype=None, interpret: bool = False,
-                   acc_dtype=None) -> Callable:
+                   acc_dtype=None,
+                   vmem_limit_bytes: Optional[int] = None) -> Callable:
     """Build the ``pl.pallas_call`` a ``RecurrentSchedule`` describes.
 
     The driver generalizes ``emit_pallas``'s sigma init/step/flush contract
@@ -1015,21 +1091,23 @@ def emit_recurrent(rs: StreamingSchedule, *, scale: float = 1.0,
                             out_dtype=out_dtype, acc_dtype=acc_dtype)
     outs = (rs.out,) + rs.state_outs
     out_dtypes = (out_dtype,) + (acc_dtype,) * len(rs.state_outs)
+    contract = kind_contract(rs.state.kind if rs.state else "online_softmax")
+    pos = (contract.pos_input % ni
+           if contract is not None and contract.pos_input is not None
+           else None)
     call = pl.pallas_call(
         body,
         grid=rs.grid_extents,
-        in_specs=[pl.BlockSpec(opn.block, _index_map(opn.grid_dims,
-                                                     opn.offsets,
-                                                     opn.page_table,
-                                                     opn.page_slot_dim))
-                  for opn in rs.ins],
+        in_specs=[_in_spec(opn, scalar=(i == pos))
+                  for i, opn in enumerate(rs.ins)],
         out_specs=[pl.BlockSpec(o.block, _index_map(o.grid_dims, o.offsets))
                    for o in outs],
         out_shape=[jax.ShapeDtypeStruct(o.shape, dt)
                    for o, dt in zip(outs, out_dtypes)],
         scratch_shapes=scratch,
         compiler_params=compiler_params(
-            dimension_semantics=rs.dimension_semantics),
+            dimension_semantics=rs.dimension_semantics,
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )
 
@@ -1070,7 +1148,8 @@ def emit_recurrent_bundle(bundle: ScheduleBundle, *, scale: float = 1.0,
     kern = emit_recurrent(rs, scale=scale, causal=causal,
                           logical_stream=logical_stream,
                           out_dtype=out_dtype, interpret=interpret,
-                          acc_dtype=getattr(bundle, "acc_dtype", "float32"))
+                          acc_dtype=bundle.acc_dtype,
+                          vmem_limit_bytes=bundle.vmem_limit_bytes)
     out_slices = tuple(slice(0, d) for d in bundle.out_shape)
     exports = bool(rs.state_outs)
 
@@ -1114,7 +1193,8 @@ def emit_bundle(bundle: ScheduleBundle, *, out_dtype=None,
     """
     sch = bundle.schedule
     kern = emit_pallas(sch, out_dtype=out_dtype, interpret=interpret,
-                       acc_dtype=getattr(bundle, "acc_dtype", "float32"))
+                       acc_dtype=bundle.acc_dtype,
+                       vmem_limit_bytes=bundle.vmem_limit_bytes)
 
     prep = []
     for spec, logical in zip(sch.ins, bundle.in_shapes):
@@ -1159,8 +1239,6 @@ def emit_shard_map(plan, mesh, local_fn: Optional[Callable] = None, *,
     global_out``; operands bind exactly as in the single-chip path (storage
     shapes), only globally sized.
     """
-    from repro.distributed.sharding import shard_map
-
     plan.check_mesh(mesh)
     if local_fn is None:
         if use_kernel:
@@ -1186,5 +1264,5 @@ def emit_shard_map(plan, mesh, local_fn: Optional[Callable] = None, *,
                 raise ValueError(f"unknown collective kind {step.kind!r}")
         return y if out_dtype is None else y.astype(out_dtype)
 
-    return shard_map(body, mesh, in_specs=plan.jax_in_specs(),
-                     out_specs=plan.jax_out_spec())
+    return jax.shard_map(body, mesh=mesh, in_specs=plan.jax_in_specs(),
+                         out_specs=plan.jax_out_spec(), check_vma=False)

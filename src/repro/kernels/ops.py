@@ -387,6 +387,18 @@ def _matmul_sharded(x2, w2, transpose_b, hw, interp, use_kernel, mesh,
     return fn(x2, w2)
 
 
+def _ambient_mesh():
+    """The multi-device mesh of an enclosing ``with mesh:`` block, outside
+    any ``shard_map`` body.  The SPMD partitioner cannot split a compiled
+    Mosaic kernel, so under such a mesh the kernel GEMM runs per shard
+    through its derived plan instead."""
+    from repro.distributed.sharding import _current_mesh
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    mesh = _current_mesh()
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
 def matmul(x: jax.Array, w: jax.Array, *, transpose_b: bool = False,
            out_dtype=None, interpret: Optional[bool] = None,
            hardware: Optional[HardwareEntry] = None,
@@ -408,7 +420,10 @@ def matmul(x: jax.Array, w: jax.Array, *, transpose_b: bool = False,
     ``mesh``/``shard``/``replicate_out`` lift the GEMM one level further to
     named device axes (roles ``{"m", "n", "k"}``; sharding "k" derives the
     tensor-parallel psum) and run the same body per shard through the
-    derived ``DistributedPlan`` — see ``repro.distributed.plan``.
+    derived ``DistributedPlan`` — see ``repro.distributed.plan``.  A
+    compiled kernel called inside a multi-device ``with mesh:`` block
+    takes that path on the enclosing mesh (rows over its first axis,
+    columns over its second).
     """
     kdim = x.shape[-1]
     if transpose_b:
@@ -427,6 +442,8 @@ def matmul(x: jax.Array, w: jax.Array, *, transpose_b: bool = False,
     out_dtype = jnp.dtype(out_dtype or x.dtype)
     x2 = x.reshape(-1, kdim)
     use_kernel = hw.backend == "pallas" or bool(interpret)
+    if mesh is None and use_kernel and not interp:
+        mesh = _ambient_mesh()
     if mesh is not None:
         y = _matmul_sharded(x2, w2, transpose_b, hw, interp, use_kernel,
                             mesh, shard, replicate_out)

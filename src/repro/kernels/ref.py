@@ -174,8 +174,9 @@ def ssd_scan_ref(xdt: jax.Array, dA: jax.Array, B: jax.Array, C: jax.Array,
     log decay (``dt * A``, <= 0), ``B/C (b,s,n)`` the state in/out
     projections.  Returns ``(y (b,s,h,p) f32, final state (b,h,p,n) f32)``.
     The per-chunk factoring mirrors the emitted kernel body step for step
-    (same einsum structure, same order of operations), which is what makes
-    the interpret-mode kernel bit-identical to this oracle.
+    (per head, the same 2-D contractions in the same order, batched over
+    ``b``), which is what makes the interpret-mode kernel bit-identical to
+    this oracle.
     """
     b, s, h, p = xdt.shape
     n = B.shape[-1]
@@ -188,27 +189,29 @@ def ssd_scan_ref(xdt: jax.Array, dA: jax.Array, B: jax.Array, C: jax.Array,
     tril = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]
     neg_inf = jnp.float32(semiring.MASK_NEG_INF)
 
+    def dot(contract):
+        return jax.vmap(lambda x, y: jax.lax.dot_general(
+            x, y, (contract, ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32))
+
     def step(h_prev, inp):
         xb, dab, Bb, Cb = inp                       # (b,q,h,p) (b,q,h) ...
-        csh = jnp.transpose(jnp.cumsum(dab, axis=1), (0, 2, 1))  # (b,h,i)
-        seg = csh[..., :, None] - csh[..., None, :]              # (b,h,i,j)
-        L = jnp.exp(jnp.where(tril, seg, neg_inf))
         G = jnp.einsum("bin,bjn->bij", Cb, Bb,
                        preferred_element_type=jnp.float32)
-        P = G[:, None] * L                                       # (b,h,i,j)
-        y = jnp.einsum("bhij,bjhp->bihp", P, xb,
-                       preferred_element_type=jnp.float32)
-        in_decay = jnp.exp(csh)                                  # (b,h,i)
-        t_off = jnp.einsum("bin,bhpn->bihp", Cb, h_prev,
-                           preferred_element_type=jnp.float32)
-        y = y + t_off * jnp.transpose(in_decay, (0, 2, 1))[..., None]
-        total = csh[..., -1]                                     # (b,h)
-        decay_states = jnp.exp(total[..., None] - csh)           # (b,h,j)
-        xd = xb * jnp.transpose(decay_states, (0, 2, 1))[..., None]
-        S = jnp.einsum("bjn,bjhp->bhpn", Bb, xd,
-                       preferred_element_type=jnp.float32)
-        h_new = jnp.exp(total)[..., None, None] * h_prev + S
-        return h_new, y
+        ys, hs = [], []
+        for hh in range(h):
+            csh = jnp.cumsum(dab[:, :, hh], axis=1)[..., None]   # (b,i,1)
+            seg = csh - jnp.swapaxes(csh, 1, 2)                  # (b,i,j)
+            L = jnp.exp(jnp.where(tril, seg, neg_inf))
+            xh = xb[:, :, hh]                                    # (b,j,p)
+            hp = h_prev[:, hh]                                   # (b,p,n)
+            y = dot(((1,), (0,)))(G * L, xh)
+            y = y + dot(((1,), (1,)))(Cb, hp) * jnp.exp(csh)
+            total = csh[:, q - 1:q]                              # (b,1,1)
+            xd = xh * jnp.exp(total - csh)
+            hs.append(jnp.exp(total) * hp + dot(((0,), (0,)))(xd, Bb))
+            ys.append(y)
+        return jnp.stack(hs, axis=1), jnp.stack(ys, axis=2)
 
     init = (jnp.zeros((b, h, p, n), jnp.float32) if init_state is None
             else init_state.astype(jnp.float32))
@@ -391,78 +394,85 @@ def ssd_bwd_ref(C: jax.Array, B: jax.Array, dY: jax.Array, X: jax.Array,
     ``C/B (b,nc,q,n)``, ``dY/X (b,nc,q,h,p)``, ``dA (b,nc,q,h)``,
     ``Hin (b,nc,h,p,n)`` (the saved per-chunk state checkpoints, reversed
     the same way) and ``dHf (b,h,p,n)``.  Mirrors the emitted kernel body
-    einsum for einsum (same replay of the forward factoring, same
-    cotangent chaining order), batched over the leading b.  Returns
+    step for step (same replay of the forward factoring, per head, same
+    cotangent chaining order), one batch row at a time.  Returns
     ``(dX, dh0, dB, dC, ddA)`` f32 in the same reversed chunk order."""
     b, nc, q, n = C.shape
+    h = X.shape[3]
+    f32 = jnp.float32
     tril = jnp.arange(q)[:, None] >= jnp.arange(q)[None, :]
-    neg_inf = jnp.float32(semiring.MASK_NEG_INF)
-    Cc = C.astype(jnp.float32)
-    Bc = B.astype(jnp.float32)
-    dYc = dY.astype(jnp.float32)
-    Xc = X.astype(jnp.float32)
-    dAc = dA.astype(jnp.float32)
-    Hc_all = Hin.astype(jnp.float32)
-    last = jnp.arange(q)[None, :] == q - 1
+    tril_f = jnp.where(tril, 1.0, 0.0).astype(f32)
+    neg_inf = f32(semiring.MASK_NEG_INF)
+    last = (jnp.arange(q) == q - 1)[:, None]
+
+    def dot(x, y, contract):
+        return jax.lax.dot_general(x, y, (contract, ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST,
+                                   preferred_element_type=f32)
+
+    def chunk(dh_all, Cb, Bb, dYb, Xb, dAb, Hc):
+        """One batch row's chunk: (q, n) / (q, h, p) / (q, h) / (h, p, n)."""
+        G = jnp.einsum("in,jn->ij", Cb, Bb, preferred_element_type=f32)
+
+        def head(hh, carry):
+            dB, dC, dG, dxs, ddas, dhs = carry
+            csh = jnp.cumsum(dAb[:, hh])[:, None]                # (i,1)
+            L = jnp.exp(jnp.where(tril, csh - csh.T, neg_inf))
+            P = G * L
+            in_decay = jnp.exp(csh)
+            hc, dh = Hc[hh], dh_all[hh]                          # (p,n)
+            t_off = dot(Cb, hc, ((1,), (1,)))
+            total = csh[q - 1:q]                                 # (1,1)
+            decay = jnp.exp(total - csh)
+            xh, dyh = Xb[:, hh], dYb[:, hh]                      # (q,p)
+            xd = xh * decay
+            dtotal = jnp.sum(jnp.sum(dh * hc, axis=1, keepdims=True),
+                             axis=0, keepdims=True) * jnp.exp(total)
+            dh_prev = jnp.exp(total) * dh
+            dB = dB + dot(xd, dh, ((1,), (0,)))
+            dxd = dot(Bb, dh, ((1,), (1,)))
+            dx = dxd * decay
+            ddec = jnp.sum(dxd * xh, axis=1, keepdims=True)
+            dtotal = dtotal + jnp.sum(ddec * decay, axis=0, keepdims=True)
+            dcsh = -(ddec * decay)
+            dt_off = dyh * in_decay
+            dcsh = dcsh + jnp.sum(dyh * t_off, axis=1,
+                                  keepdims=True) * in_decay
+            dC = dC + dot(dt_off, hc, ((1,), (0,)))
+            dh_prev = dh_prev + dot(dt_off, Cb, ((0,), (0,)))
+            dP = dot(dyh, xh, ((1,), (1,)))
+            dx = dx + dot(P, dyh, ((0,), (0,)))
+            dG = dG + dP * L
+            dseg = jnp.where(tril, dP * G * L, 0.0)
+            dcsh = (dcsh + jnp.sum(dseg, axis=1, keepdims=True)
+                    - dot(dseg, jnp.ones((q, 1), f32), ((0,), (0,))))
+            dcsh = dcsh + jnp.where(last, dtotal, 0.0)
+            # suffix sum over i >= j as the kernel runs it: dcsh against
+            # the causal mask
+            ddas = ddas.at[hh].set(dot(dcsh, tril_f, ((0,), (0,)))[0])
+            return (dB, dC, dG, dxs.at[:, hh].set(dx), ddas,
+                    dhs.at[hh].set(dh_prev))
+
+        zeros = jnp.zeros((q, n), f32)
+        dB, dC, dG, dX, ddaT, dh_new = jax.lax.fori_loop(
+            0, h, head, (zeros, zeros, jnp.zeros((q, q), f32),
+                         jnp.zeros(Xb.shape, f32), jnp.zeros((h, q), f32),
+                         jnp.zeros(dh_all.shape, f32)))
+        dC = dC + dot(dG, Bb, ((1,), (0,)))
+        dB = dB + dot(dG, Cb, ((0,), (0,)))
+        return dh_new, (dX, dB, dC, ddaT.T)
 
     def step(dh, inp):
-        Cb, Bb, dYb, Xb, dAb, Hc = inp
-        csh = jnp.transpose(jnp.cumsum(dAb, axis=1), (0, 2, 1))   # (b,h,i)
-        seg = csh[..., :, None] - csh[..., None, :]
-        L = jnp.exp(jnp.where(tril, seg, neg_inf))
-        G = jnp.einsum("bin,bjn->bij", Cb, Bb,
-                       preferred_element_type=jnp.float32)
-        P = G[:, None] * L
-        in_decay = jnp.exp(csh)
-        t_off = jnp.einsum("bin,bhpn->bihp", Cb, Hc,
-                           preferred_element_type=jnp.float32)
-        total = csh[..., -1]
-        decay_states = jnp.exp(total[..., None] - csh)
-        Xd = Xb * jnp.transpose(decay_states, (0, 2, 1))[..., None]
-        dtotal = jnp.einsum("bhpn,bhpn->bh", dh, Hc,
-                            preferred_element_type=jnp.float32) * \
-            jnp.exp(total)
-        dh_prev = jnp.exp(total)[..., None, None] * dh
-        dBb = jnp.einsum("bhpn,bjhp->bjn", dh, Xd,
-                         preferred_element_type=jnp.float32)
-        dXd = jnp.einsum("bjn,bhpn->bjhp", Bb, dh,
-                         preferred_element_type=jnp.float32)
-        dXb = dXd * jnp.transpose(decay_states, (0, 2, 1))[..., None]
-        ddec = jnp.einsum("bjhp,bjhp->bhj", dXd, Xb,
-                          preferred_element_type=jnp.float32)
-        dtotal = dtotal + jnp.sum(ddec * decay_states, axis=2)
-        dcsh = -(ddec * decay_states)
-        dt_off = dYb * jnp.transpose(in_decay, (0, 2, 1))[..., None]
-        din_decay = jnp.transpose(jnp.sum(dYb * t_off, axis=-1), (0, 2, 1))
-        dcsh = dcsh + din_decay * in_decay
-        dCb = jnp.einsum("bihp,bhpn->bin", dt_off, Hc,
-                         preferred_element_type=jnp.float32)
-        dh_prev = dh_prev + jnp.einsum("bin,bihp->bhpn", Cb, dt_off,
-                                       preferred_element_type=jnp.float32)
-        dP = jnp.einsum("bihp,bjhp->bhij", dYb, Xb,
-                        preferred_element_type=jnp.float32)
-        dXb = dXb + jnp.einsum("bhij,bihp->bjhp", P, dYb,
-                               preferred_element_type=jnp.float32)
-        dG = jnp.sum(dP * L, axis=1)
-        dL = dP * G[:, None]
-        dseg = jnp.where(tril, dL * L, 0.0)
-        dcsh = dcsh + dseg.sum(axis=3) - dseg.sum(axis=2)
-        dCb = dCb + jnp.einsum("bij,bjn->bin", dG, Bb,
-                               preferred_element_type=jnp.float32)
-        dBb = dBb + jnp.einsum("bij,bin->bjn", dG, Cb,
-                               preferred_element_type=jnp.float32)
-        dcsh = dcsh + jnp.where(last, dtotal[..., None], 0.0)
-        ddAb = jnp.transpose(jnp.flip(
-            jnp.cumsum(jnp.flip(dcsh, axis=2), axis=2), axis=2), (0, 2, 1))
-        return dh_prev, (dXb, dBb, dCb, ddAb)
+        # batch rows one by one: the kernel's exact 2-D contractions
+        outs = [chunk(dh[i], *(a[i] for a in inp)) for i in range(b)]
+        return jax.tree.map(lambda *t: jnp.stack(t), *outs)
 
     dh0, (dX, dB, dC, ddA) = jax.lax.scan(
-        step, dHf.astype(jnp.float32),
-        (Cc.transpose(1, 0, 2, 3), Bc.transpose(1, 0, 2, 3),
-         dYc.transpose(1, 0, 2, 3, 4), Xc.transpose(1, 0, 2, 3, 4),
-         dAc.transpose(1, 0, 2, 3), Hc_all.transpose(1, 0, 2, 3, 4)))
-    return (dX.transpose(1, 0, 2, 3, 4), dh0, dB.transpose(1, 0, 2, 3),
-            dC.transpose(1, 0, 2, 3), ddA.transpose(1, 0, 2, 3))
+        step, dHf.astype(f32),
+        tuple(jnp.moveaxis(a.astype(f32), 1, 0)
+              for a in (C, B, dY, X, dA, Hin)))
+    return (jnp.moveaxis(dX, 0, 1), dh0, jnp.moveaxis(dB, 0, 1),
+            jnp.moveaxis(dC, 0, 1), jnp.moveaxis(ddA, 0, 1))
 
 
 def ipophp_ref(a: jax.Array, b: jax.Array, mode: str) -> jax.Array:

@@ -193,8 +193,6 @@ def analyze(lowered, info, hardware) -> dict:
     n_chips = mesh.devices.size
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):        # older jax: one dict per device
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     bytes_ = float(ca.get("bytes accessed", 0.0))
 
@@ -249,8 +247,6 @@ def _cost_metrics(lowered) -> dict:
     """flops / bytes / per-op collective bytes of one compiled variant."""
     compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):        # older jax: one dict per device
-        ca = ca[0] if ca else {}
     coll = collective_bytes_from_hlo(compiled.as_text())
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),

@@ -22,11 +22,21 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before importing jax")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return _auto_mesh(shape, axes, devices)
+
+
+def _auto_mesh(shape, axes, devices):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules
+    (``distributed.sharding``) place arrays by constraints and leave the
+    rest to the partitioner, which ``Explicit`` axes (the default of
+    ``make_mesh``) refuse."""
+    import jax
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(dp: int = 1, tp: int = 1):
     """Small mesh over however many local devices exist (tests/examples)."""
     import jax
     devices = jax.devices()[:dp * tp]
-    return jax.make_mesh((dp, tp), ("data", "model"), devices=devices)
+    return _auto_mesh((dp, tp), ("data", "model"), devices)

@@ -15,6 +15,7 @@ import time
 import jax
 
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import registry
 from repro.serving import ServeEngine
 
@@ -32,6 +33,7 @@ def main(argv=None):
     ap.add_argument("--pool-pages", type=int, default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     params, _ = registry.init(cfg, jax.random.PRNGKey(args.seed))

@@ -20,10 +20,12 @@ import numpy as np
 
 from repro.checkpoint import Checkpointer
 from repro.configs import get_config
+from repro.core.hardware import detect_hardware
 from repro.data import PipelineConfig, SyntheticLM
 from repro.distributed import sharding as shard_rules
 from repro.distributed.compression import CompressionConfig
 from repro.distributed.fault import Coordinator, ElasticManager, StepWatchdog
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import registry
 from repro.optim.adamw import AdamWConfig
@@ -48,6 +50,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     dp = args.dp or max(len(jax.devices()) // args.tp, 1)
@@ -70,7 +73,8 @@ def main(argv=None):
                                           args.batch, seed=args.seed), cfg)
         step_fn = jax.jit(
             ts_mod.make_train_step(cfg, opt_cfg, comp, args.microbatches),
-            donate_argnums=(0,))
+            donate_argnums=(0,),
+            compiler_options=detect_hardware().xla_options())
 
         ckpt = Checkpointer(args.ckpt_dir) if args.ckpt_dir else None
         start = 0

@@ -18,7 +18,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import constrain, shard_map
+from repro.distributed.sharding import constrain
 from repro.kernels import ops
 from repro.models.common import ArchConfig, Collector
 from repro.models.layers import _gate_act
@@ -221,7 +221,7 @@ def _apply_moe_shardmap(p: dict, x: jax.Array, cfg: ArchConfig, mesh
     # checkpoint INSIDE the shard_map: outer remat treats the shard_map call
     # as opaque and would otherwise save every internal expert intermediate
     # (measured: 0.94 GiB f32 per layer on llama4-scout)
-    y, aux, z, dropped = shard_map(
+    y, aux, z, dropped = jax.shard_map(
         jax.checkpoint(body), mesh=mesh,
         in_specs=(P(batch_spec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None)),

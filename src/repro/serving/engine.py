@@ -384,24 +384,28 @@ class ServeEngine:
         return True
 
     # -- executables (cached on static keys only) --------------------------
+    # Every executable takes ``params`` as its first jit argument: a closed-
+    # over array would be baked into the program as a constant, once per
+    # prompt length and page table.
 
     def _prefill(self, tokens: list):
         fn = self._prefill_fns.get(len(tokens))
         if fn is None:
-            fn = jax.jit(lambda t: registry.prefill(
-                self.params, self.cfg, {"tokens": t}))
+            fn = jax.jit(lambda params, t: registry.prefill(
+                params, self.cfg, {"tokens": t}))
             self._prefill_fns[len(tokens)] = fn
-        return fn(jnp.asarray([tokens], jnp.int32))
+        return fn(self.params, jnp.asarray([tokens], jnp.int32))
 
     def _paged_decode_fn(self, table: tuple):
         """The jitted paged decode step for one page table — THE derived
         ``windowed_decode`` kernel reading through the table's psi view."""
         fn = self._decode_fns.get(table)
         if fn is None:
-            fn = jax.jit(functools.partial(
-                transformer.decode_step_paged, self.params, self.cfg,
-                page_table=table, page=self.page,
-                interpret=self.interpret))
+            def run(params, toks, poss, pools, _table=table):
+                return transformer.decode_step_paged(
+                    params, self.cfg, toks, poss, pools, page_table=_table,
+                    page=self.page, interpret=self.interpret)
+            fn = functools.partial(jax.jit(run), self.params)
             self._decode_fns[table] = fn
         return fn
 
@@ -412,20 +416,22 @@ class ServeEngine:
         device and only the (max_slots,) token vector crosses to host."""
         fn = self._decode_fns.get(tables)
         if fn is None:
-            def run(toks, poss, pools, _tables=tables):
+            def run(params, toks, poss, pools, _tables=tables):
                 logits, pools = transformer.decode_step_paged_batched(
-                    self.params, self.cfg, toks, poss, pools,
+                    params, self.cfg, toks, poss, pools,
                     page_tables=_tables, page=self.page,
                     interpret=self.interpret)
                 return jnp.argmax(logits, axis=-1), pools
-            fn = jax.jit(run)
+            fn = functools.partial(jax.jit(run), self.params)
             self._decode_fns[tables] = fn
         return fn
 
     def _contig_decode_fn(self):
         fn = self._decode_fns.get(())
         if fn is None:
-            fn = jax.jit(functools.partial(registry.decode_step,
-                                           self.params, self.cfg))
+            def run(params, toks, poss, cache):
+                return registry.decode_step(params, self.cfg, toks, poss,
+                                            cache)
+            fn = functools.partial(jax.jit(run), self.params)
             self._decode_fns[()] = fn
         return fn

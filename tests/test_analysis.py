@@ -326,14 +326,13 @@ def test_planned_collective_combined_summary_parsing():
     """``"a+b"`` summaries union their allowed prims; an unknown component
     anywhere in the chain is named in the finding."""
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("x",))
-    from jax.experimental.shard_map import shard_map as jshard_map
 
     def ring(x):
         return jax.lax.ppermute(x, "x", [(0, 0)])
 
-    fn = jshard_map(ring, mesh=mesh,
-                    in_specs=jax.sharding.PartitionSpec("x"),
-                    out_specs=jax.sharding.PartitionSpec("x"))
+    fn = jax.shard_map(ring, mesh=mesh,
+                       in_specs=jax.sharding.PartitionSpec("x"),
+                       out_specs=jax.sharding.PartitionSpec("x"))
     x = jnp.zeros((4,), jnp.float32)
     # traced ppermute against its own plan: clean; against a combined
     # summary that does not include it: unplanned

@@ -74,7 +74,7 @@ SUBPROC = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.distributed.sharding import shard_map
+    from jax import shard_map
     from repro.distributed import collectives as cl
 
     mesh = jax.make_mesh((8,), ("x",))

@@ -500,7 +500,8 @@ key = jax.random.PRNGKey(0)
 data = SyntheticLM(PipelineConfig(cfg.vocab_size, 16, 8), cfg)
 batch = jax.tree.map(jnp.asarray, data.global_batch(0))
 state, _ = ts.init_state(cfg, key)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 _, m0 = jax.jit(ts.make_train_step(cfg))(state, batch)
 _, m1 = jax.jit(ts.make_train_step(cfg, planned_mesh=mesh))(state, batch)
 a, b = float(m0["loss"]), float(m1["loss"])

@@ -316,6 +316,15 @@ def test_registry_detects_and_overrides():
         hw.get_entry("dgx-imaginary")
 
 
+def test_registry_detects_tpu_by_device_kind():
+    """A TPU maps to its entry by ``device_kind``; a kind with no entry
+    raises and names itself instead of borrowing another chip's table."""
+    assert hw.entry_for_device("tpu", "TPU v5 lite") == "tpu_v5e"
+    assert hw.entry_for_device("cpu", "cpu") == "cpu"
+    with pytest.raises(RuntimeError, match="TPU v4"):
+        hw.entry_for_device("tpu", "TPU v4")
+
+
 def test_vmem_validation_rejects_oversized_blocks():
     huge = BlockChoice(bm=4096, bk=4096, bn=4096, vmem_bytes=0,
                        arithmetic_intensity=0, utilization=1)

@@ -376,3 +376,42 @@ def test_engine_contiguous_fallback_ssm(mamba):
     results = engine.run()
     ref = greedy_generate(params, cfg, prompt, n_new=4, cache_len=16)
     assert results[rid]["tokens"] == np.asarray(ref[0, 6:]).tolist()
+
+
+def _param_constants(lowered, params) -> list:
+    """Constants in the lowered program whose type is a parameter's."""
+    import re
+    text = lowered.as_text()
+    consts = set(re.findall(r"stablehlo\.constant dense<[^>]*> : "
+                            r"(tensor<[^>]*>)", text))
+    types = {"tensor<" + "x".join(map(str, p.shape)) + "x"
+             + {"float32": "f32", "bfloat16": "bf16"}[str(p.dtype)] + ">"
+             for p in jax.tree.leaves(params) if p.size >= 64}
+    return sorted(consts & types)
+
+
+def test_engine_executables_take_params_as_arguments(gemma, mamba):
+    """Every engine executable binds ``params`` as a jit argument: a
+    closed-over array would be baked into the program as a constant, once
+    per prompt length and page table (gigabytes at full width)."""
+    for cfg, params in (gemma, mamba):
+        engine = ServeEngine(cfg, params, max_slots=2, max_len=16, page=4,
+                             interpret=True)
+        engine.submit(list(range(1, 6)), 2)
+        engine.run()
+        toks = jnp.zeros((1, 5), jnp.int32)
+        lowered = [engine._prefill_fns[5].lower(params, toks)]
+        if engine.batched:
+            fn = engine._batched_decode_fn(((0, 1), (0, 0)))
+            rest = (jnp.zeros((2,), jnp.int32), jnp.asarray([4, -1]),
+                    engine.pool.pools)
+        else:
+            fn = engine._contig_decode_fn()
+            cache = transformer.init_cache(cfg, 1, 16)
+            rest = (jnp.zeros((1,), jnp.int32), jnp.asarray([4]), cache)
+        assert fn.args == (params,)
+        lowered.append(fn.func.lower(*fn.args, *rest))
+        for low in lowered:
+            args, _ = low.args_info
+            assert jax.tree.structure(args[0]) == jax.tree.structure(params)
+            assert not _param_constants(low, params), cfg.name

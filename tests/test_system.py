@@ -49,11 +49,35 @@ def test_train_resume_from_checkpoint_is_bitwise_consistent(tmp_path):
 
 def test_serve_driver_end_to_end():
     from repro.launch.serve import main
-    results = main(["--arch", "gemma-2b", "--reduced", "--requests", "2",
-                    "--prompt-len", "4", "--new-tokens", "4",
-                    "--max-slots", "2", "--page", "4"])
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        results = main(["--arch", "gemma-2b", "--reduced", "--requests", "2",
+                        "--prompt-len", "4", "--new-tokens", "4",
+                        "--max-slots", "2", "--page", "4"])
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
     assert len(results) == 2
     assert all(len(r["tokens"]) == 4 for r in results.values())
+
+
+def test_compile_cache_dir_follows_env(monkeypatch, tmp_path):
+    """Unset, the entry points keep JAX's persistent cache at the fixed
+    ``<repo>/.jax_cache``; set, ``JAX_COMPILATION_CACHE_DIR`` wins and
+    no other directory is configured in code."""
+    from repro.launch import cache
+    default = os.path.join(ROOT, ".jax_cache")
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert cache.compile_cache_dir() == default
+        assert cache.enable_compile_cache() == default
+        assert jax.config.jax_compilation_cache_dir == default
+        jax.config.update("jax_compilation_cache_dir", prev)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_greedy_generation_is_deterministic():
