@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device, in
+percent (1 - union of device-op intervals / window).  Layer: device.
+Moves ``out_tok_per_s``."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])
