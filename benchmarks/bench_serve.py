@@ -101,7 +101,7 @@ def _run_pass(engine, trace: list[dict]) -> dict:
             req = pending.pop(0)
             rids.append(engine.submit(req["prompt"], req["max_new"],
                                       now=now))
-        emitted = engine.step(now=clock())
+        emitted = engine.step(clock(), clock)
         if emitted and decode_t0 is None:
             decode_t0 = clock()
         n_decoded += len(emitted)

@@ -172,6 +172,9 @@ def embed_tokens(params: dict, tokens: jax.Array, cfg: ArchConfig) -> jax.Array:
     return constrain(x, "batch", None, None)
 
 
+# every op of the head, the table's pad in ``ops.matmul`` among them,
+# carries ``head`` in its op name, which the device trace reports
+@jax.named_scope("head")
 def logits_from_hidden(params: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
     # with a planned mesh, the vocab head is column-sharded over "model":
     # the derived plan lands the spec on the right STORED dim of the tied

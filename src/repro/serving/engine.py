@@ -29,15 +29,26 @@ Families without a paged KV view (ssm, hybrid, moe, mla, vlm) serve
 through per-slot contiguous caches and ``registry.decode_step`` under
 the same admission/slot scheduler, so one engine fronts every
 architecture in the registry.
+
+Each iteration records host spans on the profiler's clock
+(``jax.profiler.TraceAnnotation``; inert unless a trace is running):
+``engine.step`` holds ``engine.admit``, one ``engine.prefill`` per
+admitted request (``n`` tokens prefilled, until its first token is on the
+host), ``engine.launch`` (the decode dispatch, ``n`` launches),
+``engine.sync`` (the one host transfer) and ``engine.emit``.  The jitted
+steps are named ``engine_prefill``, ``engine_decode``,
+``engine_decode_paged`` and ``engine_decode_batched``, which the device
+trace shows as their module names.
 """
 from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.kernels import ops
 from repro.models import registry, transformer
@@ -83,8 +94,8 @@ class ServeEngine:
     sizes the shared slab pool; ``page=None`` takes the page size from
     ``ops.default_decode_page`` — the solved stream block IS the page.
     ``interpret`` rides through to the kernels (interpret-mode Pallas on
-    CPU).  The caller supplies timestamps (``now``) so latency metrics
-    use one clock.
+    CPU).  The caller supplies timestamps (``now``, or a ``clock`` to
+    ``step``/``run``) so latency metrics use one clock.
     """
 
     def __init__(self, cfg: ArchConfig, params: Optional[dict] = None, *,
@@ -128,8 +139,8 @@ class ServeEngine:
             else None)
         self.dtype = dtype
         #: decode-step executions since construction (a batched launch
-        #: counts once however many slots it covers) — the numerator of
-        #: the bench's ``kernel_calls_per_token``
+        #: counts once however many slots it covers) — the denominator of
+        #: the benchmark's ``engine.tokens_per_launch``
         self.kernel_calls = 0
         self._waiting: list[Request] = []
         self._slots: list[_Slot] = []
@@ -157,16 +168,37 @@ class ServeEngine:
         self._out[rid] = []
         return rid
 
-    def step(self, now: float = 0.0) -> list[tuple[int, int]]:
+    def step(self, now: float = 0.0, clock=None) -> list[tuple[int, int]]:
         """One engine iteration: admit, then decode every active slot —
         ONE batched kernel launch on the paged path, a per-slot loop with
         one deferred host transfer otherwise.  Returns the ``(rid,
-        token)`` pairs emitted."""
-        emitted = self._admit(now)
-        if self.batched:
-            emitted.extend(self._decode_batched(now))
-        else:
-            emitted.extend(self._decode_sequential(now))
+        token)`` pairs emitted.  With ``clock`` (e.g. ``time.perf_counter``)
+        a request's ``admit_t``, ``first_tok_t`` and ``done_t`` are read
+        from it as they happen (a token once it is on the host); without,
+        they are ``now``."""
+        stamp = (lambda: now) if clock is None else clock
+        with TraceAnnotation("engine.step"):
+            emitted = self._admit(stamp)
+            calls = self.kernel_calls
+            with TraceAnnotation("engine.launch") as span:
+                launched, toks = (self._launch_batched() if self.batched
+                                  else self._launch_sequential())
+                span.set_metadata(n=self.kernel_calls - calls)
+            if not launched:
+                return emitted
+            with TraceAnnotation("engine.sync"):
+                toks = jax.device_get(toks)      # ONE sync per iteration
+            with TraceAnnotation("engine.emit"):
+                for slot, i in launched:
+                    if slot not in self._slots:
+                        # evicted after its launch by a later slot's
+                        # allocation: drop the token — greedy decode
+                        # recomputes it identically on re-admission
+                        continue
+                    tok = self._emit(slot, int(toks[i]), stamp)
+                    self._retire_if_done(slot, stamp)
+                    if tok is not None:
+                        emitted.append((slot.req.rid, tok))
         return emitted
 
     @property
@@ -176,9 +208,9 @@ class ServeEngine:
     def run(self, now: float = 0.0, clock=None) -> dict:
         """Step until idle; returns ``{rid: {"tokens", "request"}}``.
         ``clock`` (e.g. ``time.perf_counter``) refreshes ``now`` between
-        iterations for latency metrics."""
+        iterations and stamps requests within them (``step``)."""
         while not self.idle:
-            self.step(now if clock is None else clock())
+            self.step(now if clock is None else clock(), clock)
         return self.results()
 
     def results(self) -> dict:
@@ -187,26 +219,29 @@ class ServeEngine:
 
     # -- scheduling --------------------------------------------------------
 
-    def _admit(self, now: float) -> list[tuple[int, int]]:
+    def _admit(self, stamp: Callable[[], float]) -> list[tuple[int, int]]:
         emitted = []
-        while self._waiting and len(self._slots) < self.max_slots:
-            req = self._waiting[0]
-            try:
-                slot = self._start(req, now)
-            except OutOfPages:
-                if not self._evict(protect=None):
-                    break               # nothing evictable; wait
-                continue
-            self._waiting.pop(0)
-            self._slots.append(slot)
-            tok = self._first_token(slot, now)
-            if tok is not None:
-                emitted.append((req.rid, tok))
-            self._retire_if_done(slot, now)
+        with TraceAnnotation("engine.admit"):
+            while self._waiting and len(self._slots) < self.max_slots:
+                req = self._waiting[0]
+                try:
+                    slot, tok = self._start(req, stamp)
+                except OutOfPages:
+                    if not self._evict(protect=None):
+                        break               # nothing evictable; wait
+                    continue
+                self._waiting.pop(0)
+                self._slots.append(slot)
+                if tok is not None:
+                    emitted.append((req.rid, tok))
+                self._retire_if_done(slot, stamp)
         return emitted
 
-    def _start(self, req: Request, now: float) -> _Slot:
-        """Prefill the request's tokens-so-far into a fresh slot."""
+    def _start(self, req: Request, stamp: Callable[[], float]
+               ) -> tuple[_Slot, Optional[int]]:
+        """Claim a fresh slot for the request's tokens-so-far (its
+        stacked-table row and, paged, the pages its prefill fills),
+        prefill it and bring its first token to the host."""
         tokens = list(req.prompt) + list(self._out[req.rid])
         slot = _Slot(req=req, tokens=tokens,
                      n_emitted=len(self._out[req.rid]))
@@ -217,28 +252,25 @@ class ServeEngine:
                            if i not in used)
         if self.paged:
             slot.slabs = self.pool.alloc(pages_needed(s0, self.page))
-        logits, cache = self._prefill(tokens)
-        if self.paged:
-            self.pool.write_prefill(cache, slot.slabs, s0)
-        else:
-            slot.cache = transformer.prefill_cache_to_decode(
-                self.cfg, cache, self.max_len)
-            if slot.cache is None:
-                raise NotImplementedError(
-                    f"family {self.cfg.family!r} has no forward->decode "
-                    f"cache re-layout; the engine cannot serve it")
-        slot._logits = logits
-        if req.admit_t is None:
-            req.admit_t = now
-        return slot
+        with TraceAnnotation("engine.prefill", n=s0):
+            if req.admit_t is None:
+                req.admit_t = stamp()
+            logits, cache = self._prefill(tokens)
+            if self.paged:
+                self.pool.write_prefill(cache, slot.slabs, s0)
+            else:
+                slot.cache = transformer.prefill_cache_to_decode(
+                    self.cfg, cache, self.max_len)
+                if slot.cache is None:
+                    raise NotImplementedError(
+                        f"family {self.cfg.family!r} has no forward->decode "
+                        f"cache re-layout; the engine cannot serve it")
+            tok = self._emit(slot, int(jnp.argmax(logits[0])), stamp)
+        return slot, tok
 
-    def _first_token(self, slot: _Slot, now: float) -> Optional[int]:
-        tok = int(jnp.argmax(slot._logits[0]))
-        del slot._logits
-        return self._emit(slot, tok, now)
-
-    def _decode_batched(self, now: float) -> list[tuple[int, int]]:
-        """Decode every active paged slot in ONE derived-kernel launch.
+    def _launch_batched(self) -> tuple[list, Optional[jax.Array]]:
+        """Decode every active paged slot in ONE derived-kernel launch:
+        ``([(slot, row of the token vector)], device token vector)``.
 
         Page allocation for all slots happens first (it may evict — a
         victim simply drops out of this iteration's batch, exactly as it
@@ -263,7 +295,7 @@ class ServeEngine:
             live.append(slot)
         live = [s for s in live if s in self._slots]
         if not live:
-            return []
+            return [], None
         by_row = {s.row: s for s in live}
         # trim the view to the widest LIVE slot: shorter tables mean
         # fewer streamed grid steps per launch.  Width growth re-keys
@@ -287,22 +319,16 @@ class ServeEngine:
                               self.pool.pools)
         self.pool.update(pools)
         self.kernel_calls += 1
-        next_toks = jax.device_get(next_toks)      # ONE sync per iteration
-        emitted = []
-        for slot in live:
-            tok = self._emit(slot, int(next_toks[slot.row]), now)
-            self._retire_if_done(slot, now)
-            if tok is not None:
-                emitted.append((slot.req.rid, tok))
-        return emitted
+        return [(s, s.row) for s in live], next_toks
 
-    def _decode_sequential(self, now: float) -> list[tuple[int, int]]:
+    def _launch_sequential(self) -> tuple[list, Optional[jax.Array]]:
         """The per-slot fallback (contiguous families, ``batched=False``):
-        one decode launch per slot, but sampling stays on device and the
-        stacked token vector transfers ONCE after every slot has
-        launched — JAX's async dispatch overlaps the launches, and no
-        slot blocks the host per token."""
-        pending = []                      # (slot, device argmax scalar)
+        one decode launch per slot, ``([(slot, index)], stacked device
+        argmax)``.  Sampling stays on device and the token vector
+        transfers ONCE after every slot has launched — JAX's async
+        dispatch overlaps the launches, and no slot blocks the host per
+        token."""
+        pending = []
         for slot in list(self._slots):
             if slot not in self._slots:   # evicted by an earlier ensure
                 continue
@@ -324,36 +350,27 @@ class ServeEngine:
             self.kernel_calls += 1
             pending.append((slot, jnp.argmax(logits[0])))
         if not pending:
-            return []
-        toks = jax.device_get(jnp.stack([t for _, t in pending]))
-        emitted = []
-        for (slot, _), tok in zip(pending, toks):
-            if slot not in self._slots:
-                # evicted after its launch by a later slot's allocation:
-                # drop the token — greedy decode recomputes it identically
-                # on re-admission
-                continue
-            tok = self._emit(slot, int(tok), now)
-            self._retire_if_done(slot, now)
-            if tok is not None:
-                emitted.append((slot.req.rid, tok))
-        return emitted
+            return [], None
+        return ([(slot, i) for i, (slot, _) in enumerate(pending)],
+                jnp.stack([t for _, t in pending]))
 
-    def _emit(self, slot: _Slot, tok: int, now: float) -> Optional[int]:
+    def _emit(self, slot: _Slot, tok: int,
+              stamp: Callable[[], float]) -> Optional[int]:
         if slot.req.first_tok_t is None:
-            slot.req.first_tok_t = now
+            slot.req.first_tok_t = stamp()
         slot.tokens.append(tok)
         slot.n_emitted += 1
         self._out[slot.req.rid].append(tok)
         return tok
 
-    def _retire_if_done(self, slot: _Slot, now: float) -> None:
+    def _retire_if_done(self, slot: _Slot,
+                        stamp: Callable[[], float]) -> None:
         done = (slot.n_emitted >= slot.req.max_new or
                 (self.eos_id is not None and
                  slot.tokens[-1] == self.eos_id) or
                 len(slot.tokens) >= self.max_len)
         if done and slot in self._slots:
-            slot.req.done_t = now
+            slot.req.done_t = stamp()
             if self.paged:
                 self.pool.free(slot.slabs)
             self._slots.remove(slot)
@@ -391,8 +408,9 @@ class ServeEngine:
     def _prefill(self, tokens: list):
         fn = self._prefill_fns.get(len(tokens))
         if fn is None:
-            fn = jax.jit(lambda params, t: registry.prefill(
-                params, self.cfg, {"tokens": t}))
+            def engine_prefill(params, t):
+                return registry.prefill(params, self.cfg, {"tokens": t})
+            fn = jax.jit(engine_prefill)
             self._prefill_fns[len(tokens)] = fn
         return fn(self.params, jnp.asarray([tokens], jnp.int32))
 
@@ -401,11 +419,11 @@ class ServeEngine:
         ``windowed_decode`` kernel reading through the table's psi view."""
         fn = self._decode_fns.get(table)
         if fn is None:
-            def run(params, toks, poss, pools, _table=table):
+            def engine_decode_paged(params, toks, poss, pools, _table=table):
                 return transformer.decode_step_paged(
                     params, self.cfg, toks, poss, pools, page_table=_table,
                     page=self.page, interpret=self.interpret)
-            fn = functools.partial(jax.jit(run), self.params)
+            fn = functools.partial(jax.jit(engine_decode_paged), self.params)
             self._decode_fns[table] = fn
         return fn
 
@@ -416,22 +434,24 @@ class ServeEngine:
         device and only the (max_slots,) token vector crosses to host."""
         fn = self._decode_fns.get(tables)
         if fn is None:
-            def run(params, toks, poss, pools, _tables=tables):
+            def engine_decode_batched(params, toks, poss, pools,
+                                      _tables=tables):
                 logits, pools = transformer.decode_step_paged_batched(
                     params, self.cfg, toks, poss, pools,
                     page_tables=_tables, page=self.page,
                     interpret=self.interpret)
                 return jnp.argmax(logits, axis=-1), pools
-            fn = functools.partial(jax.jit(run), self.params)
+            fn = functools.partial(jax.jit(engine_decode_batched),
+                                   self.params)
             self._decode_fns[tables] = fn
         return fn
 
     def _contig_decode_fn(self):
         fn = self._decode_fns.get(())
         if fn is None:
-            def run(params, toks, poss, cache):
+            def engine_decode(params, toks, poss, cache):
                 return registry.decode_step(params, self.cfg, toks, poss,
                                             cache)
-            fn = functools.partial(jax.jit(run), self.params)
+            fn = functools.partial(jax.jit(engine_decode), self.params)
             self._decode_fns[()] = fn
         return fn

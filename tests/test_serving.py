@@ -415,3 +415,142 @@ def test_engine_executables_take_params_as_arguments(gemma, mamba):
             args, _ = low.args_info
             assert jax.tree.structure(args[0]) == jax.tree.structure(params)
             assert not _param_constants(low, params), cfg.name
+
+
+# -- engine spans, step names and request stamps -----------------------------
+
+def _serve_steps(cfg, params, prompts, max_new, trace_dir=None):
+    """Serve ``prompts`` step by step, under the profiler when
+    ``trace_dir`` is given.  Returns the engine and, per step, the
+    increase of ``kernel_calls`` and the rids whose first token came."""
+    engine = ServeEngine(cfg, params, max_slots=3, max_len=16, page=4,
+                         interpret=True)
+    for p in prompts:
+        engine.submit(p, max_new)
+    steps, seen = [], set()
+    if trace_dir is not None:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        while not engine.idle:
+            calls = engine.kernel_calls
+            new = {rid for rid, _ in engine.step() if rid not in seen}
+            seen |= new
+            steps.append((engine.kernel_calls - calls, new))
+    finally:
+        if trace_dir is not None:
+            jax.profiler.stop_trace()
+    return engine, steps
+
+
+def _read_trace(trace_dir):
+    """The ``engine.*`` host spans ``(name, start, end, stats)`` and the
+    ``hlo_module`` names of the traced ops."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    spans, modules = [], set()
+    for plane in ProfileData.from_file(path).planes:
+        for ln in plane.lines:
+            for e in ln.events:
+                stats = dict(e.stats)
+                if e.name.startswith("engine."):
+                    spans.append((e.name, e.start_ns, e.end_ns, stats))
+                if "hlo_module" in stats:
+                    modules.add(stats["hlo_module"])
+    return spans, modules
+
+
+@pytest.mark.parametrize("family", ["mamba", "gemma"])
+def test_engine_spans_tree_and_served_tokens(family, request, tmp_path):
+    """Each ``engine.step`` holds one ``engine.admit`` (with one
+    ``engine.prefill`` per request admitted, ``n`` = tokens prefilled),
+    exactly one ``engine.launch`` whose ``n`` is the step's
+    increase of ``kernel_calls``, and at most one ``engine.sync`` followed
+    by ``engine.emit``; the device work carries the steps' names; and the
+    tokens served are the same with the profiler off."""
+    cfg, params = request.getfixturevalue(family)
+    key = jax.random.PRNGKey(3)
+    prompts = [jax.random.randint(k, (n,), 0, cfg.vocab_size).tolist()
+               for k, n in zip(jax.random.split(key, 4), (5, 6, 4, 7))]
+    engine, steps = _serve_steps(cfg, params, prompts, 3, str(tmp_path))
+    assert engine.batched == (family == "gemma")
+    spans, modules = _read_trace(str(tmp_path))
+    decode = "jit_engine_decode_batched" if engine.batched else "jit_engine_decode"
+    assert {"jit_engine_prefill", decode} <= modules
+
+    step_spans = sorted((s for s in spans if s[0] == "engine.step"),
+                        key=lambda s: s[1])
+    assert len(step_spans) == len(steps)
+    n_children = 0
+    for (_, a, b, _), (calls, admitted) in zip(step_spans, steps):
+        inside = sorted((s for s in spans if s[0] != "engine.step"
+                         and a <= s[1] and s[2] <= b), key=lambda s: s[1])
+        n_children += len(inside)
+        by = {}
+        for s in inside:
+            by.setdefault(s[0], []).append(s)
+        assert len(by["engine.admit"]) == 1 and len(by["engine.launch"]) == 1
+        assert len(by.get("engine.sync", [])) <= 1
+        assert len(by.get("engine.emit", [])) == len(by.get("engine.sync", []))
+        (admit,), (launch,) = by["engine.admit"], by["engine.launch"]
+        assert launch[3]["n"] == calls
+        prefills = by.get("engine.prefill", [])
+        assert all(admit[1] <= p[1] and p[2] <= admit[2] for p in prefills)
+        assert sorted(p[3]["n"] for p in prefills) == sorted(
+            len(prompts[rid]) for rid in admitted)
+        order = [s[0] for s in inside if s[0] != "engine.prefill"]
+        assert order == ["engine.admit", "engine.launch", "engine.sync",
+                         "engine.emit"][:len(order)]
+    assert n_children + len(step_spans) == len(spans)   # none outside a step
+
+    untraced, _ = _serve_steps(cfg, params, prompts, 3)
+    assert {r: v["tokens"] for r, v in untraced.results().items()} == {
+        r: v["tokens"] for r, v in engine.results().items()}
+
+
+def test_engine_jitted_steps_are_named(gemma, mamba):
+    """The jitted steps carry stable names, which the device trace shows
+    as their module names (``jit_<name>``)."""
+    cfg, params = mamba
+    engine = ServeEngine(cfg, params, max_slots=2, max_len=16)
+    engine.submit(list(range(1, 6)), 2)
+    engine.run()
+    assert engine._prefill_fns[5].__name__ == "engine_prefill"
+    assert engine._contig_decode_fn().func.__name__ == "engine_decode"
+    cfg, params = gemma
+    engine = ServeEngine(cfg, params, max_slots=2, max_len=16, page=4,
+                         interpret=True)
+    assert engine._paged_decode_fn((0, 1)).func.__name__ == (
+        "engine_decode_paged")
+    fn = engine._batched_decode_fn(((0, 1), (0, 0)))
+    assert fn.func.__name__ == "engine_decode_batched"
+    lowered = fn.func.lower(*fn.args, jnp.zeros((2,), jnp.int32),
+                            jnp.asarray([4, -1]), engine.pool.pools)
+    assert "@jit_engine_decode_batched" in lowered.as_text()
+
+
+def test_engine_stamps_tokens_on_the_callers_clock(mamba):
+    """With a clock, a request admitted and prefilled in one step is
+    stamped as it goes: ``first_tok_t`` once the token is on the host,
+    after ``admit_t``; with none every stamp is the step's ``now``."""
+    cfg, params = mamba
+    ticks = iter(range(1, 1000))
+    engine = ServeEngine(cfg, params, max_slots=2, max_len=16)
+    rid = engine.submit(list(range(1, 6)), 2)
+    engine.step(0.0, clock=lambda: float(next(ticks)))  # admit .. retire
+    req = engine.results()[rid]["request"]
+    assert 0.0 < req.admit_t < req.first_tok_t < req.done_t
+    engine = ServeEngine(cfg, params, max_slots=2, max_len=16)
+    rid = engine.submit(list(range(1, 6)), 2)
+    engine.run(clock=lambda: float(next(ticks)))
+    req = engine.results()[rid]["request"]
+    assert req.admit_t < req.first_tok_t < req.done_t
+    engine = ServeEngine(cfg, params, max_slots=2, max_len=16)
+    rid = engine.submit(list(range(1, 6)), 2)
+    engine.step(0.0)
+    req = engine.results()[rid]["request"]
+    assert req.admit_t == req.first_tok_t == req.done_t == 0.0
