@@ -25,10 +25,19 @@ sequence is evicted (slabs freed, request re-queued with its tokens so
 far) and re-prefills when re-admitted — recompute preemption, the
 standard continuous-batching fallback.
 
-Families without a paged KV view (ssm, hybrid, moe, mla, vlm) serve
-through per-slot contiguous caches and ``registry.decode_step`` under
-the same admission/slot scheduler, so one engine fronts every
-architecture in the registry.
+Families without a paged KV view (ssm, dense MHA with one head a group,
+MLA) serve contiguous, under the same admission/slot scheduler: the slot
+axis of their decode cache is lifted, so ONE stacked cache holds every
+slot on the batch axis (axis 1, under the layer axis), each slot pins one
+row for its whole residency (lowest free row at admission), and ONE
+``registry.decode_step`` launch per iteration decodes every live slot,
+with greedy argmax on device.  A launch covers rows ``[0, kb)``, where
+``kb`` is the smallest power of two past the highest live row (capped at
+``max_slots``): occupancy buckets, so a lone slot does not pay for the
+state traffic of dead rows.  A dead row inside the bucket decodes token
+0 at position 0; rows never interact, and admission overwrites the whole
+row with the prefilled cache.  Families with no forward->decode cache
+re-layout (windowed dense, hybrid, moe, vlm) are refused at admission.
 
 Each iteration records host spans on the profiler's clock
 (``jax.profiler.TraceAnnotation``; inert unless a trace is running):
@@ -38,7 +47,9 @@ host), ``engine.launch`` (the decode dispatch, ``n`` launches),
 ``engine.sync`` (the one host transfer) and ``engine.emit``.  The jitted
 steps are named ``engine_prefill``, ``engine_decode``,
 ``engine_decode_paged`` and ``engine_decode_batched``, which the device
-trace shows as their module names.
+trace shows as their module names; ``engine_read_rows`` and
+``engine_write_rows`` read and write rows of the stacked contiguous
+cache.
 """
 from __future__ import annotations
 
@@ -54,6 +65,23 @@ from repro.kernels import ops
 from repro.models import registry, transformer
 from repro.models.common import ArchConfig
 from repro.serving.cache import OutOfPages, PagePool, pages_needed
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def engine_write_rows(cache: dict, rows: dict, row) -> dict:
+    """``rows`` written into the stacked ``cache`` from batch row ``row``
+    on (axis 1), in place: the cache is donated, and the row is traced, so
+    one executable serves every row."""
+    return jax.tree.map(
+        lambda c, r: jax.lax.dynamic_update_slice_in_dim(c, r, row, axis=1),
+        cache, rows)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def engine_read_rows(cache: dict, n: int) -> dict:
+    """Rows ``[0, n)`` of the stacked ``cache`` (axis 1), in one dispatch
+    for every leaf."""
+    return jax.tree.map(lambda t: t[:, :n], cache)
 
 
 @dataclass
@@ -75,8 +103,7 @@ class _Slot:
     tokens: list            # prompt + emitted tokens, in order
     n_emitted: int = 0
     slabs: list = field(default_factory=list)     # the page table
-    cache: Optional[dict] = None                  # contiguous fallback only
-    row: int = -1                                 # stacked-table row (batched)
+    row: int = -1              # stacked-table or stacked-cache row
 
 
 def _paged_capable(cfg: ArchConfig) -> bool:
@@ -116,7 +143,8 @@ class ServeEngine:
         self.eos_id = eos_id
         self.paged = _paged_capable(cfg)
         # batched multi-slot decode rides the paged psi view (the stacked
-        # table IS the slot lift); contiguous families fall back per-slot
+        # table IS the slot lift); contiguous families lift the slot axis
+        # of their cache instead (``_launch_contiguous``)
         self.batched = self.paged and batched is not False
         if batched and not self.paged:
             raise ValueError(
@@ -138,6 +166,10 @@ class ServeEngine:
             PagePool(cfg, pool_pages, self.page, dtype) if self.paged
             else None)
         self.dtype = dtype
+        #: the stacked contiguous decode cache, ``max_slots`` rows on axis
+        #: 1; allocated at the first admission from that decode cache's
+        #: own shapes and dtypes
+        self._cache: Optional[dict] = None
         #: decode-step executions since construction (a batched launch
         #: counts once however many slots it covers) — the denominator of
         #: the benchmark's ``engine.tokens_per_launch``
@@ -170,8 +202,10 @@ class ServeEngine:
 
     def step(self, now: float = 0.0, clock=None) -> list[tuple[int, int]]:
         """One engine iteration: admit, then decode every active slot —
-        ONE batched kernel launch on the paged path, a per-slot loop with
-        one deferred host transfer otherwise.  Returns the ``(rid,
+        ONE launch over the stacked page table (paged) or the stacked
+        contiguous cache bucketed by occupancy (contiguous), a per-slot
+        loop with one deferred host transfer for ``batched=False``; either
+        way the token vector is the one host transfer.  Returns the ``(rid,
         token)`` pairs emitted.  With ``clock`` (e.g. ``time.perf_counter``)
         a request's ``admit_t``, ``first_tok_t`` and ``done_t`` are read
         from it as they happen (a token once it is on the host); without,
@@ -181,8 +215,10 @@ class ServeEngine:
             emitted = self._admit(stamp)
             calls = self.kernel_calls
             with TraceAnnotation("engine.launch") as span:
-                launched, toks = (self._launch_batched() if self.batched
-                                  else self._launch_sequential())
+                launched, toks = (
+                    self._launch_batched() if self.batched
+                    else self._launch_sequential() if self.paged
+                    else self._launch_contiguous())
                 span.set_metadata(n=self.kernel_calls - calls)
             if not launched:
                 return emitted
@@ -240,16 +276,16 @@ class ServeEngine:
     def _start(self, req: Request, stamp: Callable[[], float]
                ) -> tuple[_Slot, Optional[int]]:
         """Claim a fresh slot for the request's tokens-so-far (its
-        stacked-table row and, paged, the pages its prefill fills),
-        prefill it and bring its first token to the host."""
+        stacked row and, paged, the pages its prefill fills), prefill it
+        (contiguous: into its row of the stacked cache) and bring its
+        first token to the host."""
         tokens = list(req.prompt) + list(self._out[req.rid])
+        used = {s.row for s in self._slots}
         slot = _Slot(req=req, tokens=tokens,
-                     n_emitted=len(self._out[req.rid]))
+                     n_emitted=len(self._out[req.rid]),
+                     row=min(i for i in range(self.max_slots)
+                             if i not in used))
         s0 = len(tokens)
-        if self.batched:
-            used = {s.row for s in self._slots}
-            slot.row = min(i for i in range(self.max_slots)
-                           if i not in used)
         if self.paged:
             slot.slabs = self.pool.alloc(pages_needed(s0, self.page))
         with TraceAnnotation("engine.prefill", n=s0):
@@ -259,12 +295,18 @@ class ServeEngine:
             if self.paged:
                 self.pool.write_prefill(cache, slot.slabs, s0)
             else:
-                slot.cache = transformer.prefill_cache_to_decode(
+                cache = transformer.prefill_cache_to_decode(
                     self.cfg, cache, self.max_len)
-                if slot.cache is None:
+                if cache is None:
                     raise NotImplementedError(
                         f"family {self.cfg.family!r} has no forward->decode "
                         f"cache re-layout; the engine cannot serve it")
+                if self._cache is None:
+                    self._cache = jax.tree.map(
+                        lambda t: jnp.zeros((t.shape[0], self.max_slots)
+                                            + t.shape[2:], t.dtype), cache)
+                self._cache = engine_write_rows(self._cache, cache,
+                                                slot.row)
             tok = self._emit(slot, int(jnp.argmax(logits[0])), stamp)
         return slot, tok
 
@@ -321,32 +363,52 @@ class ServeEngine:
         self.kernel_calls += 1
         return [(s, s.row) for s in live], next_toks
 
+    def _launch_contiguous(self) -> tuple[list, Optional[jax.Array]]:
+        """Decode every live contiguous slot in ONE launch over rows
+        ``[0, kb)`` of the stacked cache: ``([(slot, its row)], device
+        token vector)``.  ``kb`` is the smallest power of two past the
+        highest live row, capped at ``max_slots`` (one executable per
+        bucket).  At ``kb == max_slots`` the launch's cache output IS the
+        new stacked cache; a smaller bucket's output goes back through
+        the donated row write at offset 0."""
+        if not self._slots:
+            return [], None
+        kb = min(self.max_slots,
+                 1 << max(s.row for s in self._slots).bit_length())
+        toks, poss = [0] * kb, [0] * kb     # dead rows: token 0 at 0
+        for slot in self._slots:
+            toks[slot.row] = slot.tokens[-1]
+            poss[slot.row] = len(slot.tokens) - 1
+        full = kb == self.max_slots
+        cache = self._cache if full else engine_read_rows(self._cache, kb)
+        next_toks, cache = self._contig_decode_fn()(
+            jnp.asarray(toks, jnp.int32), jnp.asarray(poss, jnp.int32),
+            cache)
+        self._cache = (cache if full else
+                       engine_write_rows(self._cache, cache, 0))
+        self.kernel_calls += 1
+        return [(s, s.row) for s in self._slots], next_toks
+
     def _launch_sequential(self) -> tuple[list, Optional[jax.Array]]:
-        """The per-slot fallback (contiguous families, ``batched=False``):
-        one decode launch per slot, ``([(slot, index)], stacked device
-        argmax)``.  Sampling stays on device and the token vector
-        transfers ONCE after every slot has launched — JAX's async
-        dispatch overlaps the launches, and no slot blocks the host per
-        token."""
+        """The per-slot paged path (``batched=False``): one decode launch
+        per slot, ``([(slot, index)], stacked device argmax)``.  Sampling
+        stays on device and the token vector transfers ONCE after every
+        slot has launched — JAX's async dispatch overlaps the launches,
+        and no slot blocks the host per token."""
         pending = []
         for slot in list(self._slots):
             if slot not in self._slots:   # evicted by an earlier ensure
                 continue
             pos = len(slot.tokens) - 1    # feed the newest token here
-            if self.paged:
-                try:
-                    self._ensure_pages(slot, pos + 1)
-                except OutOfPages:
-                    continue              # pool saturated; retry next step
-                fn = self._paged_decode_fn(tuple(slot.slabs))
-                logits, pools = fn(
-                    jnp.asarray([slot.tokens[-1]], jnp.int32),
-                    jnp.asarray([pos], jnp.int32), self.pool.pools)
-                self.pool.update(pools)
-            else:
-                logits, slot.cache = self._contig_decode_fn()(
-                    jnp.asarray([slot.tokens[-1]], jnp.int32),
-                    jnp.asarray([pos], jnp.int32), slot.cache)
+            try:
+                self._ensure_pages(slot, pos + 1)
+            except OutOfPages:
+                continue                  # pool saturated; retry next step
+            fn = self._paged_decode_fn(tuple(slot.slabs))
+            logits, pools = fn(
+                jnp.asarray([slot.tokens[-1]], jnp.int32),
+                jnp.asarray([pos], jnp.int32), self.pool.pools)
+            self.pool.update(pools)
             self.kernel_calls += 1
             pending.append((slot, jnp.argmax(logits[0])))
         if not pending:
@@ -447,11 +509,17 @@ class ServeEngine:
         return fn
 
     def _contig_decode_fn(self):
+        """The jitted contiguous decode step over a stacked cache,
+        ``fn(toks, poss, cache) -> (greedy tokens, new cache)``; one
+        executable per occupancy bucket (the rows of ``toks``).  The cache
+        is not donated, so the input stays a live array after the call:
+        a wrapper may hand it back as the state."""
         fn = self._decode_fns.get(())
         if fn is None:
             def engine_decode(params, toks, poss, cache):
-                return registry.decode_step(params, self.cfg, toks, poss,
-                                            cache)
+                logits, cache = registry.decode_step(params, self.cfg, toks,
+                                                     poss, cache)
+                return jnp.argmax(logits, axis=-1), cache
             fn = functools.partial(jax.jit(engine_decode), self.params)
             self._decode_fns[()] = fn
         return fn

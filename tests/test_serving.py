@@ -365,8 +365,8 @@ def test_engine_batched_eviction_under_pressure_matches_isolated(gemma):
 
 
 def test_engine_contiguous_fallback_ssm(mamba):
-    """Families without a paged KV view serve through per-slot contiguous
-    caches under the same scheduler."""
+    """Families without a paged KV view serve through one stacked
+    contiguous cache under the same scheduler."""
     cfg, params = mamba
     engine = ServeEngine(cfg, params, max_slots=2, max_len=16)
     assert not engine.paged and engine.pool is None
@@ -407,14 +407,59 @@ def test_engine_executables_take_params_as_arguments(gemma, mamba):
                     engine.pool.pools)
         else:
             fn = engine._contig_decode_fn()
-            cache = transformer.init_cache(cfg, 1, 16)
-            rest = (jnp.zeros((1,), jnp.int32), jnp.asarray([4]), cache)
+            cache = transformer.init_cache(cfg, 2, 16)   # the stacked cache
+            rest = (jnp.zeros((2,), jnp.int32), jnp.asarray([4, 0]), cache)
         assert fn.args == (params,)
         lowered.append(fn.func.lower(*fn.args, *rest))
         for low in lowered:
             args, _ = low.args_info
             assert jax.tree.structure(args[0]) == jax.tree.structure(params)
             assert not _param_constants(low, params), cfg.name
+
+
+@pytest.mark.parametrize("arch", ["mamba2-780m", "stablelm-1.6b",
+                                  "minicpm3-4b"])
+def test_engine_contiguous_one_launch_per_iteration(arch, monkeypatch):
+    """Contiguous families (ssm, dense MHA, MLA) decode every live slot in
+    ONE launch per iteration over the stacked cache, bucketed by
+    occupancy: rows retire and are reused mid-run, a dead row sits inside
+    a bucket, and a lone slot on row 0 launches one row.  Every request
+    decodes exactly what it would have alone."""
+    cfg = get_config(arch, reduced=True)
+    params, _ = registry.init(cfg, jax.random.PRNGKey(0))
+    rows = []
+    real = ServeEngine._contig_decode_fn
+
+    def spy(self):
+        fn = real(self)
+
+        def call(toks, poss, cache):
+            assert {t.shape[1] for t in jax.tree.leaves(cache)} == {
+                toks.shape[0]}
+            rows.append(toks.shape[0])
+            return fn(toks, poss, cache)
+        return call
+    monkeypatch.setattr(ServeEngine, "_contig_decode_fn", spy)
+    engine = ServeEngine(cfg, params, max_slots=3, max_len=16)
+    assert not engine.paged and not engine.batched
+    key = jax.random.PRNGKey(11)
+    # rows 0-2 fill; row 1 retires and is reused twice, then lies dead
+    # under row 2; rid 0 (row 0) ends alone
+    lengths, budgets = (5, 3, 7, 4, 6), (9, 2, 5, 2, 2)
+    prompts = [jax.random.randint(k, (n,), 0, cfg.vocab_size).tolist()
+               for k, n in zip(jax.random.split(key, 5), lengths)]
+    rids = [engine.submit(p, n) for p, n in zip(prompts, budgets)]
+    while not engine.idle:
+        calls, launches = engine.kernel_calls, len(rows)
+        engine.step()
+        assert engine.kernel_calls - calls == len(rows) - launches == 1
+    assert rows == [3, 3, 3, 3, 1, 1, 1, 1]
+    results = engine.results()
+    for rid, prompt, n in zip(rids, prompts, budgets):
+        ref = greedy_generate(params, cfg, jnp.asarray([prompt], jnp.int32),
+                              n_new=n, cache_len=16)
+        assert results[rid]["tokens"] == np.asarray(
+            ref[0, len(prompt):]).tolist(), (arch, rid)
 
 
 # -- engine spans, step names and request stamps -----------------------------
