@@ -4,14 +4,15 @@ architecture (``--arch`` on all launchers).  Plus the paper's own workload
 from __future__ import annotations
 
 from repro.configs import (command_r_plus_104b, deepseek_moe_16b, gemma_2b,
-                           llama4_scout_17b_a16e, mamba2_780m, minicpm3_4b,
-                           paligemma_3b, recurrentgemma_9b, stablelm_1_6b,
-                           whisper_base)
+                           granite_4_0_h_micro, llama4_scout_17b_a16e,
+                           mamba2_780m, minicpm3_4b, paligemma_3b,
+                           recurrentgemma_9b, stablelm_1_6b, whisper_base)
 from repro.models.common import SHAPES, ArchConfig, ShapeConfig  # noqa: F401
 
 _MODULES = (command_r_plus_104b, minicpm3_4b, gemma_2b, stablelm_1_6b,
             mamba2_780m, llama4_scout_17b_a16e, deepseek_moe_16b,
-            paligemma_3b, recurrentgemma_9b, whisper_base)
+            paligemma_3b, recurrentgemma_9b, whisper_base,
+            granite_4_0_h_micro)
 
 ARCHS = {m.ARCH_ID: m for m in _MODULES}
 ARCH_IDS = tuple(ARCHS)

@@ -90,6 +90,12 @@ MLA_DIMS = (768, 256, 64, 32, 64)
 # core scores/combine (grouped, never repeats KV)
 # ---------------------------------------------------------------------------
 
+def softmax_scale(cfg: ArchConfig, hd: int) -> float:
+    """The scale on q.k: the published ``attention_multiplier`` where the
+    config sets one, else ``hd ** -0.5``."""
+    return cfg.attention_multiplier or hd ** -0.5
+
+
 def _split_groups(q: jax.Array, kv_heads: int) -> jax.Array:
     b, s, h, hd = q.shape
     return q.reshape(b, s, kv_heads, h // kv_heads, hd)
@@ -146,7 +152,7 @@ def attention_fwd(p: dict, x: jax.Array, cfg: ArchConfig, *,
             "attention")
     b, s, d = x.shape
     hd = p["wq"].shape[-1]
-    scale = hd ** -0.5
+    scale = softmax_scale(cfg, hd)
     q = _proj(x, p["wq"])
     q = constrain(q, "batch", "seq_sp", None, None) \
         if cfg.attn_sharding == "sp" else constrain(q, "batch", None, "heads", None)
@@ -228,7 +234,7 @@ def attention_decode(p: dict, x: jax.Array, cache: KV, pos: jax.Array,
     pos: (B,) absolute position of the new token."""
     b, _, d = x.shape
     hd = p["wq"].shape[-1]
-    scale = hd ** -0.5
+    scale = softmax_scale(cfg, hd)
     q = _proj(x, p["wq"])
     k = _proj(x, p["wk"])
     v = _proj(x, p["wv"])
@@ -270,7 +276,7 @@ def attention_decode_ring(p: dict, x: jax.Array, cache: KV, pos: jax.Array,
     """
     b, _, d = x.shape
     hd = p["wq"].shape[-1]
-    scale = hd ** -0.5
+    scale = softmax_scale(cfg, hd)
     wlen = cache.k.shape[1]
     q = _proj(x, p["wq"])
     k = _proj(x, p["wk"])
@@ -316,7 +322,7 @@ def attention_decode_paged(p: dict, x: jax.Array, k_pool: jax.Array,
     across tokens.  Returns ``(out (1, 1, d), k_pool, v_pool)``.
     """
     hd = p["wq"].shape[-1]
-    scale = hd ** -0.5
+    scale = softmax_scale(cfg, hd)
     q = _proj(x, p["wq"])
     k = _proj(x, p["wk"])
     v = _proj(x, p["wv"])
@@ -373,7 +379,7 @@ def attention_decode_paged_batched(p: dict, x: jax.Array,
     affecting a single live value.  One ``paged_decode_batched`` launch
     serves all slots."""
     hd = p["wq"].shape[-1]
-    scale = hd ** -0.5
+    scale = softmax_scale(cfg, hd)
     q = _proj(x, p["wq"])
     k = _proj(x, p["wk"])
     v = _proj(x, p["wv"])

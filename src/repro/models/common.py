@@ -1,8 +1,8 @@
 """Shared model-configuration schema + parameter collection utilities.
 
-One ``ArchConfig`` dataclass covers all ten assigned architecture families
-(dense / MLA / MoE / SSM / hybrid / enc-dec / VLM-stub / audio-stub); the
-per-arch files in ``repro.configs`` instantiate it.
+One ``ArchConfig`` dataclass covers every architecture family (dense / MLA /
+MoE / SSM / hybrid / Mamba-2-attention hybrid / enc-dec / VLM-stub /
+audio-stub); the per-arch files in ``repro.configs`` instantiate it.
 
 Parameters are plain nested-dict pytrees.  Every leaf is declared through a
 ``Collector`` with *logical axis names* (e.g. ``("layers", "d_model",
@@ -28,7 +28,8 @@ import numpy as np
 @dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                      # dense | ssm | moe | vlm | hybrid | audio
+    family: str                      # dense | ssm | moe | vlm | hybrid |
+                                     # mamba_hybrid | audio
     n_layers: int
     d_model: int
     n_heads: int
@@ -41,8 +42,10 @@ class ArchConfig:
     attention: str = "full"          # full | mla | none (ssm)
     local_window: int = 0            # >0 enables windowed attention layers
     layer_pattern: tuple[str, ...] = ()   # repeating group for hybrids, e.g.
-                                          # ("rglru","rglru","local") or
+                                          # ("rglru","rglru","local"),
                                           # ("local","local","local","full")
+                                          # or, mamba_hybrid, the period's
+                                          # "mamba"/"attn" mixers
     rope_theta: float = 10000.0
     rope_pct: float = 1.0            # partial rotary (stablelm: 0.25)
 
@@ -53,6 +56,15 @@ class ArchConfig:
     parallel_block: bool = False     # attn+mlp in parallel (command-r style)
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
+    norm_eps: float = 1e-6           # RMSNorm/LayerNorm and Mamba's gated norm
+    # published multipliers (granite): 0 / 1 keep the plain model
+    embedding_multiplier: float = 0.0   # >0 scales looked-up rows (replaces
+                                        # the tied sqrt(d) convention)
+    residual_multiplier: float = 1.0    # mamba_hybrid: on every sublayer's
+                                        # output before its residual add
+    attention_multiplier: float = 0.0   # >0 is the softmax scale (else
+                                        # head_dim ** -0.5)
+    logits_scaling: float = 1.0         # logits are divided by it
 
     # MoE
     moe: bool = False
@@ -117,14 +129,19 @@ class ArchConfig:
         total = emb
         active = emb
         n_att_layers = self.n_layers
-        if self.family == "ssm":
+        if self.family in ("ssm", "mamba_hybrid"):
             d_in = self.ssm_expand * d
             n_h = d_in // self.ssm_head_dim
             per = (d * (2 * d_in + 2 * self.ssm_state * 1 + n_h)  # in_proj-ish
                    + d_in * d)
-            total += self.n_layers * per
-            active = total
-            return int(total), int(active)
+            if self.family == "ssm":
+                total += self.n_layers * per
+                return int(total), int(total)
+            n_att = self.n_layers * self.layer_pattern.count("attn") \
+                // len(self.layer_pattern)
+            total += ((self.n_layers - n_att) * per + n_att * att
+                      + self.n_layers * dense_mlp)
+            return int(total), int(total)
         if self.layer_pattern:
             n_rec = sum(1 for p in self.layer_pattern if p == "rglru")
             frac_rec = n_rec / len(self.layer_pattern)

@@ -29,8 +29,8 @@ def init_norm(col: Collector, path: str, d: int, cfg: ArchConfig,
                   init="zeros")
 
 
-def apply_norm(p: dict, x: jax.Array, cfg: ArchConfig, eps: float = 1e-6
-               ) -> jax.Array:
+def apply_norm(p: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array:
+    eps = cfg.norm_eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "layernorm":
         mu = jnp.mean(xf, axis=-1, keepdims=True)
@@ -167,7 +167,9 @@ def init_embed(col: Collector, cfg: ArchConfig):
 
 def embed_tokens(params: dict, tokens: jax.Array, cfg: ArchConfig) -> jax.Array:
     x = params["embed"]["table"][tokens]
-    if cfg.tie_embeddings:
+    if cfg.embedding_multiplier:                        # published (granite)
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    elif cfg.tie_embeddings:
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)   # gemma convention
     return constrain(x, "batch", None, None)
 
@@ -192,6 +194,8 @@ def logits_from_hidden(params: dict, x: jax.Array, cfg: ArchConfig) -> jax.Array
     else:
         logits = ops.matmul(x, params["unembed"]["w"], out_dtype=jnp.float32,
                             **mesh_kw)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.logit_softcap:
         c = cfg.logit_softcap
         logits = jnp.tanh(logits / c) * c

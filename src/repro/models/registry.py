@@ -1,4 +1,4 @@
-"""Family dispatch: one uniform interface over all ten architectures.
+"""Family dispatch: one uniform interface over every architecture.
 
     init(cfg, key)                  -> (params, logical_axes)
     loss(params, cfg, batch)        -> (loss, metrics)

@@ -125,7 +125,7 @@ def apply_mamba2(p: dict, x: jax.Array, cfg: ArchConfig) -> tuple[jax.Array, SSM
     # gated RMSNorm (mamba2)
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
     yf = y.astype(jnp.float32)
-    y = (yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + 1e-6)
+    y = (yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + cfg.norm_eps)
          * p["norm_scale"].astype(jnp.float32)).astype(x.dtype)
     out = ops.matmul(y, p["w_out"], out_dtype=x.dtype)
     # cache: last conv_width-1 pre-conv inputs + final state
@@ -160,7 +160,7 @@ def decode_mamba2(p: dict, x: jax.Array, cache: SSMCache, cfg: ArchConfig
     y = y.reshape(b, din).astype(x.dtype)
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype)
     yf = y.astype(jnp.float32)
-    y = (yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + 1e-6)
+    y = (yf * jax.lax.rsqrt(jnp.mean(yf * yf, -1, keepdims=True) + cfg.norm_eps)
          * p["norm_scale"].astype(jnp.float32)).astype(x.dtype)
     out = ops.matmul(y, p["w_out"], out_dtype=x.dtype)[:, None]
     new_conv = hist[:, 1:]

@@ -2,10 +2,14 @@
 
 Layer stacks are *scanned* (params stacked on a leading "layers" axis) so the
 HLO stays O(1) in depth; hybrids scan over repeating layer *groups*
-(dimension lifting of the layer axis: L -> (groups, pattern)).  Bodies are
-rematerialized (``jax.checkpoint``) and layer-boundary activations carry a
-sequence-parallel sharding constraint so saved activations shard over the
-"model" axis too.
+(dimension lifting of the layer axis: L -> (groups, pattern)).  The
+Mamba-2/attention hybrid (``mamba_hybrid``, granite-4.0-h) keeps that lift
+under ``layers/``: every leaf leads with the period axis, then the count of
+its kind within the period (mamba mixers, attention mixers, or all layers
+for the norms and MLPs), and one scan step runs one period in its
+published order.  Bodies are rematerialized (``jax.checkpoint``) and
+layer-boundary activations carry a sequence-parallel sharding constraint
+so saved activations shard over the "model" axis too.
 
 Entry points (used by train/serve steps and the dry-run):
 
@@ -90,6 +94,15 @@ def init_lm(cfg: ArchConfig, key: jax.Array) -> tuple[dict, dict]:
         L = cfg.n_layers
         init_norm(col, "layers/ln1", cfg.d_model, cfg, _stack(L))
         ssm_mod.init_mamba2(col, "layers/mixer", cfg, _stack(L))
+    elif fam == "mamba_hybrid":
+        periods, n_mamba, n_attn = _mamba_hybrid_counts(cfg)
+        per = len(cfg.layer_pattern)
+        st = lambda n: ((periods, "layers"), (n, None))
+        init_norm(col, "layers/ln1", cfg.d_model, cfg, st(per))
+        init_norm(col, "layers/ln2", cfg.d_model, cfg, st(per))
+        ssm_mod.init_mamba2(col, "layers/mamba", cfg, st(n_mamba))
+        attn.init_attention(col, "layers/attn", cfg, st(n_attn))
+        init_mlp(col, "layers/mlp", cfg, stack=st(per))
     elif fam == "hybrid":
         pat = cfg.layer_pattern                     # e.g. (rglru, rglru, local)
         g = cfg.n_layers // len(pat)
@@ -163,6 +176,39 @@ def _moe_block(lp: dict, x: jax.Array, cfg: ArchConfig, positions,
     x = x + m_out
     x = constrain(x, "batch", "seq_sp", None)
     return x, kv, Aux(stats.aux_loss, stats.z_loss, stats.dropped_frac)
+
+
+def _mamba_hybrid_counts(cfg: ArchConfig) -> tuple[int, int, int]:
+    """(periods, mamba mixers a period, attention mixers a period)."""
+    pat = cfg.layer_pattern
+    if not pat or set(pat) - {"mamba", "attn"} or cfg.n_layers % len(pat):
+        raise ValueError(f"mamba_hybrid needs n_layers a multiple of a "
+                         f"mamba/attn pattern, not {cfg.n_layers} over {pat}")
+    n_attn = pat.count("attn")
+    return cfg.n_layers // len(pat), len(pat) - n_attn, n_attn
+
+
+def _mamba_hybrid_period(lp: dict, x: jax.Array, cfg: ArchConfig, mixer
+                         ) -> jax.Array:
+    """One period of the Mamba-2/attention hybrid in its published order:
+    each layer is ``x + r * mixer(norm(x))`` then ``x + r * mlp(norm(x))``,
+    ``r`` the residual multiplier.  ``mixer(kind, i, p, h)`` runs the
+    period's ``i``-th mixer of that kind (``p`` its parameters) on ``h``
+    and keeps its cache itself."""
+    r = cfg.residual_multiplier
+    at = lambda tree, i: jax.tree.map(lambda t: t[i], tree)
+    count = {"mamba": 0, "attn": 0}
+    for i, kind in enumerate(cfg.layer_pattern):
+        j = count[kind]
+        with jax.named_scope(kind):
+            h = apply_norm(at(lp["ln1"], i), x, cfg)
+            x = x + mixer(kind, j, at(lp[kind], j), h) * r
+        count[kind] += 1
+        with jax.named_scope("mlp"):
+            h = apply_norm(at(lp["ln2"], i), x, cfg)
+            x = x + apply_mlp(at(lp["mlp"], i), h, cfg) * r
+        x = constrain(x, "batch", "seq_sp", None)
+    return x
 
 
 # ---------------------------------------------------------------------------
@@ -248,6 +294,22 @@ def forward(params: dict, cfg: ArchConfig, tokens: jax.Array,
             xc = constrain(xc + out, "batch", "seq_sp", None)
             return xc, c
         x, cache = _scan(cfg, body, x, params["layers"])
+    elif fam == "mamba_hybrid":
+        @rm
+        def body(xc, lp):
+            caches = {"mamba": [], "attn": []}
+
+            def mixer(kind, _, p, h):
+                out, c = (ssm_mod.apply_mamba2(p, h, cfg) if kind == "mamba"
+                          else attn.attention_fwd(p, h, cfg,
+                                                  positions=positions))
+                caches[kind].append(c)
+                return out
+            xc = _mamba_hybrid_period(lp, xc, cfg, mixer)
+            return xc, {k: jax.tree.map(lambda *t: jnp.stack(t), *v)
+                        for k, v in caches.items()}
+        x, cache = _scan(cfg, body, x, params["layers"])
+        # cache: {"mamba": SSMCache, "attn": KV}, leaves (periods, count, B, ...)
     elif fam == "hybrid":
         pat = cfg.layer_pattern
 
@@ -347,6 +409,15 @@ def init_cache(cfg: ArchConfig, batch: int, cache_len: int,
         c = ssm_mod.init_ssm_cache(cfg, batch, dtype)
         return {"layers": jax.tree.map(
             lambda t: jnp.broadcast_to(t[None], (cfg.n_layers,) + t.shape), c)}
+    if fam == "mamba_hybrid":
+        # the slot (batch) axis sits on axis 1 of every leaf, under the
+        # layers of its kind, as the engine's stacked cache needs
+        periods, n_mamba, n_attn = _mamba_hybrid_counts(cfg)
+        c = ssm_mod.init_ssm_cache(cfg, batch, dtype)
+        return {"mamba": jax.tree.map(lambda t: jnp.broadcast_to(
+                    t[None], (periods * n_mamba,) + t.shape), c),
+                "attn": _kv_cache((periods * n_attn,), batch, cache_len, kv,
+                                  hd, dtype)}
     if fam == "hybrid":
         pat = cfg.layer_pattern
         g = cfg.n_layers // len(pat)
@@ -463,6 +534,31 @@ def decode_step(params: dict, cfg: ArchConfig, tokens: jax.Array,
             return xc + out, c
         x, nc = _scan(cfg, body, x, (params["layers"], cache["layers"]))
         new_cache = {"layers": nc}
+    elif fam == "mamba_hybrid":
+        # the whole cache rides the scan's carry and each layer updates its
+        # own index in place: no per-period stack of new states
+        periods, n_mamba, n_attn = _mamba_hybrid_counts(cfg)
+        n_kind = {"mamba": n_mamba, "attn": n_attn}
+
+        def body(carry, scan_in):
+            xc, cc = carry
+            lp, g = scan_in
+            cc = dict(cc)
+
+            def mixer(kind, i, p, h):
+                j = g * n_kind[kind] + i
+                c = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(
+                    t, j, keepdims=False), cc[kind])
+                out, c = (ssm_mod.decode_mamba2(p, h, c, cfg)
+                          if kind == "mamba"
+                          else attn.attention_decode(p, h, c, pos, cfg))
+                cc[kind] = jax.tree.map(
+                    lambda t, u: jax.lax.dynamic_update_index_in_dim(
+                        t, u.astype(t.dtype), j, 0), cc[kind], c)
+                return out
+            return (_mamba_hybrid_period(lp, xc, cfg, mixer), cc), None
+        (x, new_cache), _ = _scan(cfg, body, (x, dict(cache)),
+                                  (params["layers"], jnp.arange(periods)))
     elif fam == "hybrid":
         pat = cfg.layer_pattern
 
@@ -536,7 +632,7 @@ def has_prefill_decode_relayout(cfg: ArchConfig) -> bool:
     forward cache (the policy is config-only, so callers can decide
     before paying for the prefill pass)."""
     return ((cfg.family == "dense" and not cfg.local_window)
-            or cfg.family == "ssm")
+            or cfg.family in ("ssm", "mamba_hybrid"))
 
 
 def prefill_cache_to_decode(cfg: ArchConfig, cache, cache_len: int):
@@ -544,19 +640,23 @@ def prefill_cache_to_decode(cfg: ArchConfig, cache, cache_len: int):
 
     Returns None for families whose decode cache has no direct forward
     equivalent — ring caches (windowed dense), grouped layer patterns,
-    hybrid stacks, vlm (prefill takes patches) — which must keep the
+    RG-LRU hybrid stacks, vlm (prefill takes patches) — which must keep the
     token-by-token ingestion scan.  Dense full-attention KV/MLA caches pad
     the sequence axis out to ``cache_len`` (later positions are masked
     until written); ssm caches carry forward unchanged — the final state
-    IS the decode state."""
+    IS the decode state.  The Mamba-2/attention hybrid does both, after
+    folding its (periods, count) axes into one layer axis."""
+    def pad(t):
+        return jnp.pad(t, [(0, 0), (0, 0), (0, cache_len - t.shape[2])] +
+                       [(0, 0)] * (t.ndim - 3))
     if cfg.family == "dense" and not cfg.local_window:
-        def pad(t):
-            return jnp.pad(t, [(0, 0), (0, 0),
-                               (0, cache_len - t.shape[2])] +
-                           [(0, 0)] * (t.ndim - 3))
         return {"layers": jax.tree.map(pad, cache)}
     if cfg.family == "ssm":
         return {"layers": cache}
+    if cfg.family == "mamba_hybrid":
+        flat = lambda t: t.reshape((-1,) + t.shape[2:])
+        return {"mamba": jax.tree.map(flat, cache["mamba"]),
+                "attn": jax.tree.map(lambda t: pad(flat(t)), cache["attn"])}
     return None
 
 

@@ -26,10 +26,12 @@ far) and re-prefills when re-admitted — recompute preemption, the
 standard continuous-batching fallback.
 
 Families without a paged KV view (ssm, dense MHA with one head a group,
-MLA) serve contiguous, under the same admission/slot scheduler: the slot
-axis of their decode cache is lifted, so ONE stacked cache holds every
-slot on the batch axis (axis 1, under the layer axis), each slot pins one
-row for its whole residency (lowest free row at admission), and ONE
+MLA, the Mamba-2/attention hybrid) serve contiguous, under the same
+admission/slot scheduler: the slot axis of their decode cache is lifted,
+so ONE stacked cache holds every slot on the batch axis (axis 1, under
+the layer axis; the hybrid's recurrent state and its K/V alike), each
+slot pins one row for its whole residency (lowest free row at
+admission), and ONE
 ``registry.decode_step`` launch per iteration decodes every live slot,
 with greedy argmax on device.  A launch covers rows ``[0, kb)``, where
 ``kb`` is the smallest power of two past the highest live row (capped at
@@ -37,7 +39,8 @@ with greedy argmax on device.  A launch covers rows ``[0, kb)``, where
 state traffic of dead rows.  A dead row inside the bucket decodes token
 0 at position 0; rows never interact, and admission overwrites the whole
 row with the prefilled cache.  Families with no forward->decode cache
-re-layout (windowed dense, hybrid, moe, vlm) are refused at admission.
+re-layout (windowed dense, the RG-LRU hybrid, moe, vlm) are refused at
+admission.
 
 Each iteration records host spans on the profiler's clock
 (``jax.profiler.TraceAnnotation``; inert unless a trace is running):

@@ -69,14 +69,14 @@ def test_smoke_decode_step(arch):
 
 CONSISTENCY_ARCHS = ["command-r-plus-104b", "minicpm3-4b", "gemma-2b",
                      "stablelm-1.6b", "mamba2-780m", "recurrentgemma-9b",
-                     "whisper-base"]
+                     "whisper-base", "granite-4.0-h-micro"]
 
 
 @pytest.mark.parametrize("arch", CONSISTENCY_ARCHS)
 def test_decode_matches_forward(arch):
     """Greedy caches must reproduce teacher-forced logits — validates every
     cache type (KV, latent MLA, SSM state, RG-LRU state, ring buffers,
-    enc-dec cross attention)."""
+    enc-dec cross attention, SSM state beside KV)."""
     cfg = get_config(arch, reduced=True).with_(remat=False)
     params, _ = registry.init(cfg, KEY)
     b, s = 2, 16
@@ -153,7 +153,7 @@ def test_ssm_chunked_matches_tiny_chunks():
 def test_param_counts_match_analytic():
     """Analytic param_count (used for MODEL_FLOPS) vs real init, per arch."""
     import numpy as np
-    for arch in ["gemma-2b", "stablelm-1.6b"]:
+    for arch in ["gemma-2b", "stablelm-1.6b", "granite-4.0-h-micro"]:
         cfg = get_config(arch)
         total, _ = cfg.param_count()
         # reduced check at full scale is too big to init; verify the analytic

@@ -418,10 +418,11 @@ def test_engine_executables_take_params_as_arguments(gemma, mamba):
 
 
 @pytest.mark.parametrize("arch", ["mamba2-780m", "stablelm-1.6b",
-                                  "minicpm3-4b"])
+                                  "minicpm3-4b", "granite-4.0-h-micro"])
 def test_engine_contiguous_one_launch_per_iteration(arch, monkeypatch):
-    """Contiguous families (ssm, dense MHA, MLA) decode every live slot in
-    ONE launch per iteration over the stacked cache, bucketed by
+    """Contiguous families (ssm, dense MHA, MLA, the Mamba-2/attention
+    hybrid with recurrent state and K/V side by side) decode every live
+    slot in ONE launch per iteration over the stacked cache, bucketed by
     occupancy: rows retire and are reused mid-run, a dead row sits inside
     a bucket, and a lone slot on row 0 launches one row.  Every request
     decodes exactly what it would have alone."""

@@ -130,8 +130,8 @@ def test_input_specs_cover_all_cells():
         leaves = jax.tree.leaves(specs)
         assert leaves and all(hasattr(l, "shape") for l in leaves)
         n_ok += 1
-    assert n_ok + n_skip == 40
-    assert n_skip == 7
+    assert n_ok + n_skip == 44
+    assert n_skip == 8
 
 
 @pytest.mark.slow
