@@ -92,7 +92,14 @@ class OperandSpec:
     metadata (batched multi-slot decode): ``page_slot_dim`` names the grid
     axis carrying the lifted slot index ``s``, and dim 0's block index
     becomes ``page_table[s][k]`` — the same select-fold lowering keyed on
-    two grid axes."""
+    two grid axes.
+
+    ``runtime_slab`` generalizes the psi view's constant slab to runtime
+    data: the leading ``PSI_AXIS`` dimension indexes the layers of a
+    stacked leaf, and its block index is the kernel's scalar-prefetched
+    slab (plus ``offsets[0]``).  Such an operand is read in place, never
+    padded: derivation made its contracted block divide the extent, and an
+    output axis's ragged edge is left to the kernel's masked edge block."""
     array: str
     axes: tuple[str, ...]
     shape: tuple[int, ...]
@@ -101,6 +108,7 @@ class OperandSpec:
     offsets: tuple[int, ...] = ()
     page_table: Optional[tuple] = None
     page_slot_dim: Optional[int] = None
+    runtime_slab: bool = False
 
     @property
     def is_psi_view(self) -> bool:
@@ -748,8 +756,35 @@ def reset_schedule_cache() -> None:
 _LANE, _SUBLANE = 128, 8
 
 
+def in_place_k_block(k: int, bk: int) -> int:
+    """The contraction block for an operand read in place (never padded):
+    the largest multiple of the lane width that divides ``k`` and does not
+    exceed the solved ``bk``, else ``k`` whole (a block equal to the full
+    extent is always legal).  3072 under a solved 2048 gives 1536."""
+    for b in range(min(bk, k) // _LANE * _LANE, 0, -_LANE):
+        if k % b == 0:
+            return b
+    return k
+
+
+def _slab_schedule(sched: Schedule, in_place: tuple[str, ...]) -> Schedule:
+    """Give each in-place operand a leading runtime-slab dimension: the
+    layer axis of its stacked leaf, block 1, its block index the kernel's
+    scalar-prefetched layer — a psi view whose slab is runtime data."""
+    def lift(spec: OperandSpec) -> OperandSpec:
+        if spec.array not in in_place:
+            return spec
+        return _dc_replace(spec, axes=(PSI_AXIS,) + spec.axes,
+                           shape=(1,) + spec.shape, block=(1,) + spec.block,
+                           grid_dims=(None,) + spec.grid_dims,
+                           offsets=(0,) + spec.offsets,
+                           runtime_slab=True)
+    return _dc_replace(sched, ins=tuple(lift(s) for s in sched.ins))
+
+
 def _build_bundle(nf: "expr_mod.NormalForm", dtype, hw_shape,
-                  blocks, acc_dtype: str = "float32") -> ScheduleBundle:
+                  blocks, acc_dtype: str = "float32",
+                  in_place: tuple[str, ...] = ()) -> ScheduleBundle:
     """Pad, lift and derive a schedule for any normalized expression.
 
     The policy generalizes the paper's fig-2 lifting: leading output axes
@@ -759,8 +794,16 @@ def _build_bundle(nf: "expr_mod.NormalForm", dtype, hw_shape,
     extents come from ``solve_blocks`` for the (mul, add) semiring; other
     semirings use fixed MXU-aligned tiles (their in-block combine
     materializes a (bm, bn, bk) intermediate, so tiles stay small).
+
+    ``in_place`` names leaves read in place from a stacked leaf through a
+    runtime slab (``OperandSpec.runtime_slab``): a contraction axis they
+    carry takes a block that divides it (``in_place_k_block``), so it never
+    pads; their output axes keep the padded grid and leave the ragged edge
+    to the kernel.
     """
     ext = nf.extent_map
+    in_place_syms = {t for leaf in nf.leaves if leaf.array in in_place
+                     for t, _ in leaf.dims if isinstance(t, str)}
     out_syms, red_syms = nf.out_axes, nf.reduce_axes
     msym = out_syms[-2] if len(out_syms) >= 2 else None
     nsym = out_syms[-1] if out_syms else None
@@ -784,6 +827,8 @@ def _build_bundle(nf: "expr_mod.NormalForm", dtype, hw_shape,
                                       dtype, hardware=hw_shape,
                                       vmem_budget_frac=0.25,
                                       materialized_combine=True)
+        if ksym in in_place_syms:
+            blocks = _dc_replace(blocks, bk=in_place_k_block(k, blocks.bk))
         bm, bk, bn = blocks.as_tuple()
         if msym:
             pads[msym] = _pad(m, bm)
@@ -814,9 +859,10 @@ def _build_bundle(nf: "expr_mod.NormalForm", dtype, hw_shape,
     order = out_syms + red_syms
     logical = tuple(ext[s] for s in order)
     padded = tuple(pads.get(s, ext[s]) for s in order)
-    return ScheduleBundle(nf.name,
-                          derive_schedule(lifted, hw_shape, dtype, acc_dtype),
-                          blocks, logical, padded,
+    sched = derive_schedule(lifted, hw_shape, dtype, acc_dtype)
+    if in_place:
+        sched = _slab_schedule(sched, in_place)
+    return ScheduleBundle(nf.name, sched, blocks, logical, padded,
                           nf.out_shape(), nf.leaf_storage_shapes(),
                           acc_dtype=acc_dtype,
                           vmem_limit_bytes=hw_shape.vmem.capacity_bytes)
@@ -1033,7 +1079,8 @@ def _expr_for_op(op: str, shapes: tuple[int, ...]) -> "expr_mod.Expr":
 
 
 def get_schedule(op, shapes=None, dtype="float32", hardware=None,
-                 blocks=None, acc_dtype: str = "float32") -> ScheduleBundle:
+                 blocks=None, acc_dtype: str = "float32",
+                 in_place: tuple[str, ...] = ()) -> ScheduleBundle:
     """LRU-cached schedule derivation keyed on the expression's normal form.
 
     New signature::
@@ -1060,6 +1107,11 @@ def get_schedule(op, shapes=None, dtype="float32", hardware=None,
 
     ``hardware`` may be a ``HardwareEntry`` (preferred — its name keys the
     cache) or a bare ``HardwareShape``.
+
+    ``in_place`` names leaves of an expression (not a recurrent form)
+    that are read in place from a stacked leaf, one layer at a time
+    through a runtime slab (see ``_build_bundle``); it is part of the
+    cache key.
     """
     if isinstance(op, str):
         warnings.warn(
@@ -1109,7 +1161,8 @@ def get_schedule(op, shapes=None, dtype="float32", hardware=None,
     if isinstance(block_key, (BlockChoice, StreamBlockChoice,
                               RecurrenceBlockChoice)):
         block_key = block_key.as_tuple()
-    key = (nf.key(), dtype_key, hw_name, block_key, acc_dtype)
+    in_place = tuple(in_place)
+    key = (nf.key(), dtype_key, hw_name, block_key, acc_dtype, in_place)
     with _lock:
         hit = _cache.get(key)
         if hit is not None:
@@ -1122,7 +1175,7 @@ def get_schedule(op, shapes=None, dtype="float32", hardware=None,
                                              acc_dtype=acc_dtype)
         else:
             bundle = _build_bundle(nf, dtype_key, hw_shape, blocks,
-                                   acc_dtype=acc_dtype)
+                                   acc_dtype=acc_dtype, in_place=in_place)
         _cache[key] = bundle
         while len(_cache) > SCHEDULE_CACHE_SIZE:
             _cache.popitem(last=False)

@@ -66,7 +66,9 @@ MAX_PAGE_TABLE_ENTRIES = 1024
 def _index_map(grid_dims: tuple[Optional[int], ...],
                offsets: tuple[int, ...] = (),
                page_table: Optional[tuple] = None,
-               page_slot_dim: Optional[int] = None) -> Callable:
+               page_slot_dim: Optional[int] = None,
+               n_prefetch: int = 0,
+               runtime_slab: bool = False) -> Callable:
     """BlockSpec index map from the operand's grid bindings.
 
     ``offsets`` add a constant block offset per dimension (a psi view's
@@ -82,7 +84,12 @@ def _index_map(grid_dims: tuple[Optional[int], ...],
     multi-slot decode): the fold runs over the row-major flattened table on
     the combined key ``s * n_steps + k``, with ``s`` read from grid axis
     ``page_slot_dim`` — same select-fold, two grid axes keying it.  The
-    entry budget applies to the flattened table."""
+    entry budget applies to the flattened table.
+
+    Under scalar prefetch the map also receives the ``n_prefetch``
+    prefetched refs after the grid indices; with ``runtime_slab`` dim 0's
+    block index adds the first of them — a psi slab read at run time
+    (the layer of a stacked weight)."""
     if page_table is not None and page_slot_dim is not None:
         n_steps = len(page_table[0])
         flat_table = tuple(t for row in page_table for t in row)
@@ -107,10 +114,13 @@ def _index_map(grid_dims: tuple[Optional[int], ...],
             slab = jnp.where(i == k, jnp.int32(t), slab)
         return slab
 
-    def imap(*gids):
+    def imap(*args):
+        gids = args[:len(args) - n_prefetch]
         idx = []
         for dim, (d, off) in enumerate(zip(grid_dims, offs)):
             i = (gids[d] if d is not None else 0) + off
+            if dim == 0 and runtime_slab:
+                i = i + args[len(gids)][0]
             if dim == 0 and flat_table is not None:
                 if n_steps is not None:
                     i = gids[page_slot_dim] * n_steps + i
@@ -183,8 +193,15 @@ def emit_pallas(schedule: Schedule, combine=None, *, out_dtype=None,
     ``preferred_element_type`` and the sigma scratch dtype; only the
     (mul, add) semiring has non-f32 accumulation paths.
     ``vmem_limit_bytes`` rides to ``compiler_params``.
+
+    An operand with ``runtime_slab`` makes the kernel take its slab as a
+    scalar-prefetch operand: ``fn(slab, *operands)``, ``slab`` an int32
+    ``(1,)`` array that every such operand's index map adds on dim 0.
+    Such operands are bound in place: their non-contracted dims may fall
+    short of the schedule's padded extent within the last block.
     """
     ni = len(schedule.ins)
+    n_prefetch = int(any(opn.runtime_slab for opn in schedule.ins))
     out_dtype = jnp.dtype(out_dtype or jnp.float32)
     acc_dtype = jnp.dtype(acc_dtype or jnp.float32)
     spec, in_keep = schedule.einsum_plan()
@@ -206,6 +223,7 @@ def emit_pallas(schedule: Schedule, combine=None, *, out_dtype=None,
         identity = rdef.identity
 
     def body(*refs):
+        refs = refs[n_prefetch:]
         o_ref = refs[ni]
         if multiplicative:
             squeezed = [
@@ -240,17 +258,23 @@ def emit_pallas(schedule: Schedule, combine=None, *, out_dtype=None,
             def _flush():
                 o_ref[...] = acc_ref[...].astype(out_dtype)
 
+    grid = dict(
+        grid=schedule.grid_extents,
+        in_specs=[pl.BlockSpec(opn.block, _index_map(
+            opn.grid_dims, opn.offsets, n_prefetch=n_prefetch,
+            runtime_slab=opn.runtime_slab)) for opn in schedule.ins],
+        out_specs=pl.BlockSpec(out_block, _index_map(
+            schedule.out.grid_dims, schedule.out.offsets,
+            n_prefetch=n_prefetch)),
+        scratch_shapes=([pltpu.VMEM(out_block, acc_dtype)]
+                        if red is not None else []))
+    if n_prefetch:
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_prefetch, **grid))
     call = pl.pallas_call(
         body,
-        grid=schedule.grid_extents,
-        in_specs=[pl.BlockSpec(opn.block, _index_map(opn.grid_dims,
-                                                     opn.offsets))
-                  for opn in schedule.ins],
-        out_specs=pl.BlockSpec(out_block, _index_map(schedule.out.grid_dims,
-                                                     schedule.out.offsets)),
+        **grid,
         out_shape=jax.ShapeDtypeStruct(schedule.out.shape, out_dtype),
-        scratch_shapes=([pltpu.VMEM(out_block, acc_dtype)]
-                        if red is not None else []),
         compiler_params=compiler_params(
             dimension_semantics=schedule.dimension_semantics,
             vmem_limit_bytes=vmem_limit_bytes),
@@ -258,9 +282,10 @@ def emit_pallas(schedule: Schedule, combine=None, *, out_dtype=None,
     )
 
     def fn(*arrays):
-        if len(arrays) != ni:
-            raise ValueError(f"{schedule.name}: expected {ni} operands")
-        for arr, opn in zip(arrays, schedule.ins):
+        if len(arrays) != ni + n_prefetch:
+            raise ValueError(f"{schedule.name}: expected {ni} operands"
+                             + (" after the slab" if n_prefetch else ""))
+        for arr, opn in zip(arrays[n_prefetch:], schedule.ins):
             if not _shape_ok(tuple(arr.shape), opn):
                 raise ValueError(
                     f"{schedule.name}: operand {opn.array} has shape "
@@ -272,11 +297,17 @@ def emit_pallas(schedule: Schedule, combine=None, *, out_dtype=None,
 
 def _shape_ok(shp: tuple[int, ...], opn) -> bool:
     """A psi-view operand may be bound with MORE leading slabs than the
-    pinned index needs; every other dim must match the schedule exactly."""
+    pinned index needs; every other dim must match the schedule exactly,
+    except that an in-place (runtime-slab) operand's dim may end inside
+    its last block (the kernel's masked edge)."""
     if len(shp) != len(opn.shape):
         return False
     if shp == opn.shape:
         return True
+    if opn.runtime_slab:
+        return shp[0] >= 1 and all(
+            t - b < s <= t for s, t, b in zip(shp[1:], opn.shape[1:],
+                                              opn.block[1:]))
     return (opn.is_psi_view and shp[0] >= opn.shape[0]
             and shp[1:] == opn.shape[1:])
 
@@ -1190,6 +1221,10 @@ def emit_bundle(bundle: ScheduleBundle, *, out_dtype=None,
     shape with the semiring's inert element, runs the emitted kernel, and
     slices the logical result back out.  The missing-inert-element error
     is only raised when padding is actually required.
+
+    A bundle with runtime-slab operands executes as ``call(slab, *arrays)``:
+    ``slab`` an int32 scalar (it may be traced), each such operand the
+    whole stacked leaf ``(L, ...)``, bound as it is — never padded.
     """
     sch = bundle.schedule
     kern = emit_pallas(sch, out_dtype=out_dtype, interpret=interpret,
@@ -1205,9 +1240,17 @@ def emit_bundle(bundle: ScheduleBundle, *, out_dtype=None,
     pad_val = sched_mod.bundle_pad_value(bundle)
     out_slices = tuple(slice(0, d) for d in bundle.out_shape)
 
+    slabbed = any(spec.runtime_slab for spec in sch.ins)
+
     def call(*arrays):
         padded = []
+        if slabbed:
+            slab, arrays = arrays[0], arrays[1:]
+            padded.append(jnp.reshape(slab, (1,)).astype(jnp.int32))
         for x, (lead, spec) in zip(arrays, prep):
+            if spec.runtime_slab:
+                padded.append(x)
+                continue
             if spec.is_psi_view:
                 if lead > 1:                 # several fixed dims -> one slab
                     x = x.reshape((-1,) + x.shape[lead:])

@@ -31,12 +31,19 @@ per-shard derived kernel all from the same lifted normal form — and runs it
 through ``shard_map``.  ``shard`` names which axes lift onto which mesh
 axes (roles ``{"m", "n", "k"}`` for matmul, plus ``"e"`` for experts; plan
 axis symbols for ``apply``); non-divisible axes fall back to replication.
+
+``layer_matmul(x, w_stacked, layer)`` — and ``matmul`` handed a
+``LayerSlab`` — is the decode bodies' GEMM: the layer axis of a stacked
+weight is lifted into the kernel's index space (a psi slab read at run
+time from a scalar-prefetched index), so no launch copies a layer out.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 from collections import OrderedDict
+from dataclasses import dataclass
 from typing import Optional, Union
 
 import jax
@@ -387,6 +394,172 @@ def _matmul_sharded(x2, w2, transpose_b, hw, interp, use_kernel, mesh,
     return fn(x2, w2)
 
 
+# ---------------------------------------------------------------------------
+# layer-indexed GEMMs: a decode body hands the stacked weight leaf and a
+# layer index; the kernel reads that layer in place through its runtime slab
+# ---------------------------------------------------------------------------
+
+@dataclass(frozen=True)
+class LayerSlab:
+    """Layer ``layer`` (an int32 scalar, may be traced) of the stacked
+    weight ``stacked`` (layers leading), not sliced out: ``matmul`` reads
+    it in place through ``layer_matmul``.  ``k_minor`` records that the
+    device stores each layer's contraction dim minor (see ``layer_slab``).
+    ``shape`` and ``reshape`` act on the one layer's view, as they would
+    on the sliced leaf."""
+    stacked: jax.Array
+    layer: jax.Array
+    k_minor: bool = False
+
+    @property
+    def shape(self) -> tuple[int, ...]:
+        return tuple(self.stacked.shape[1:])
+
+    def reshape(self, *shape: int) -> "LayerSlab":
+        out = self.stacked.reshape((self.stacked.shape[0],) + shape)
+        if self.k_minor and out.shape[1] != self.stacked.shape[1]:
+            raise ValueError("a k-minor layer slab may merge its output "
+                             "dims only, not its contraction dim")
+        return LayerSlab(out, self.layer, self.k_minor)
+
+    def sliced(self) -> jax.Array:
+        """The layer as its own array (a copy on the device)."""
+        return jax.lax.dynamic_index_in_dim(self.stacked, self.layer,
+                                            keepdims=False)
+
+
+@functools.lru_cache(maxsize=1024)
+def _default_order(device, dtype_s: str, shape: tuple[int, ...]
+                   ) -> tuple[int, ...]:
+    from jax.experimental.layout import Layout
+    return tuple(Layout.from_pjrt_layout(device.client.get_default_layout(
+        jnp.dtype(dtype_s), shape, device)).major_to_minor)
+
+
+def stored_k_minor(shape: tuple[int, ...], dtype, lead: int = 1) -> bool:
+    """Whether the target device's default layout of a stacked weight
+    ``(*lead dims, k, *n dims)`` keeps ``k`` minor, the rest in order: the
+    stored buffer is then each layer's transpose, and a kernel reads it in
+    place only as ``(n, k)``.  A TPU does this where ``n``'s last dim pads
+    its 128-lane tile and ``k`` does not (Mamba's in-projections, the
+    attention q/k/v of 64-wide heads).  The device is JAX's default device
+    (``jax.default_device``), else the first one."""
+    if len(shape) - lead < 2:
+        return False
+    dev = jax.config.jax_default_device
+    if not isinstance(dev, jax.Device):
+        dev = jax.devices(dev)[0] if isinstance(dev, str) else jax.devices()[0]
+    order = _default_order(dev, str(jnp.dtype(dtype)), tuple(shape))
+    return order == (tuple(range(lead)) + tuple(range(lead + 1, len(shape)))
+                     + (lead,))
+
+
+def layer_slab(stacked: jax.Array, layer, lead: int = 1) -> LayerSlab:
+    """Layer ``layer`` of the stacked weight leaf ``stacked``, whose first
+    ``lead`` dims index layers (flattened row-major into one layer axis,
+    a free reshape), to be read in place by ``matmul``."""
+    k_minor = stored_k_minor(stacked.shape, stacked.dtype, lead)
+    return LayerSlab(stacked.reshape((-1,) + stacked.shape[lead:]),
+                     jnp.asarray(layer, jnp.int32), k_minor)
+
+
+_gemm_reads = threading.local()
+
+
+@contextlib.contextmanager
+def count_gemm_reads():
+    """Count, while a function traces, the model GEMMs that read a stacked
+    weight in place (``layer_matmul``) and those handed a whole weight
+    (``matmul``): yields ``{"in_place": n, "whole": m}``, filled as the
+    trace runs.  A GEMM traced inside ``gemm_repeats(n)`` counts ``n``
+    times (a scan body runs once a layer)."""
+    prev = getattr(_gemm_reads, "counts", None), getattr(_gemm_reads,
+                                                           "times", 1)
+    counts = {"in_place": 0, "whole": 0}
+    _gemm_reads.counts, _gemm_reads.times = counts, 1
+    try:
+        yield counts
+    finally:
+        _gemm_reads.counts, _gemm_reads.times = prev
+
+
+@contextlib.contextmanager
+def gemm_repeats(n: int):
+    """GEMMs traced inside run ``n`` times each (the body of a scan over
+    ``n`` layers) for ``count_gemm_reads``."""
+    times = getattr(_gemm_reads, "times", 1)
+    _gemm_reads.times = times * int(n)
+    try:
+        yield
+    finally:
+        _gemm_reads.times = times
+
+
+def _count_gemm(kind: str) -> None:
+    counts = getattr(_gemm_reads, "counts", None)
+    if counts is not None:
+        counts[kind] += _gemm_reads.times
+
+
+def _layer_gemm_callable(m: int, k: int, n: int, k_minor: bool,
+                         dtype_s: str, hw_name: str, interpret: bool):
+    """The memoized kernel of ``layer_matmul``: the (m, k, n) GEMM whose
+    weight leaf "B" is read in place from a stacked leaf, through its
+    transpose where the device stores it k-minor."""
+    key = ("layer_gemm", m, k, n, k_minor, dtype_s, hw_name, interpret)
+    fn = _cache_get(key)
+    if fn is not None:
+        return fn
+    bundle = _sched.get_schedule(E.matmul_expr(m, k, n, transpose_b=k_minor),
+                                 dtype=dtype_s, hardware=get_entry(hw_name),
+                                 in_place=("B",))
+    return _cache_put(key, jax.jit(emit_bundle(
+        bundle, out_dtype="float32", interpret=interpret)))
+
+
+def layer_matmul(x: jax.Array, w: jax.Array, layer, *, out_dtype=None,
+                 k_minor: Optional[bool] = None,
+                 interpret: Optional[bool] = None,
+                 hardware: Optional[HardwareEntry] = None) -> jax.Array:
+    """``x[..., k] @ w[layer][k, ...]`` without slicing the layer out.
+
+    ``w`` is a whole stacked leaf ``(L, k, ...)`` and ``layer`` an int32
+    scalar that may be traced (a scan's layer index).  On a Pallas backend
+    the derived GEMM takes ``layer`` as a scalar-prefetch operand, and the
+    weight's BlockSpec index map returns ``(layer, kk, j)``: each layer is
+    read in place, never copied or padded (the contraction block divides
+    ``k``; a ragged output edge is the kernel's masked edge block, sliced
+    off the result).  Only the activation pads, in M.  Where the device
+    stores the leaf k-minor (``stored_k_minor``, which ``k_minor=None``
+    asks) the kernel reads the same buffer as ``(L, n, k)`` through the
+    transposed-operand schedule.  Elsewhere it is
+    ``dot(x, dynamic_index(w, layer))`` under ``matmul``'s f32
+    accumulation contract.  Not differentiable: decode bodies call it,
+    training and prefill keep ``matmul`` on sliced weights."""
+    kdim = x.shape[-1]
+    if w.shape[1] != kdim:
+        raise ValueError(f"layer_matmul contraction mismatch {x.shape} @ "
+                         f"{w.shape}[layer]")
+    _count_gemm("in_place")
+    hw, interp = _resolve(hardware, interpret)
+    out_tail = w.shape[2:]
+    w3 = w.reshape(w.shape[0], kdim, -1)
+    x2 = x.reshape(-1, kdim)
+    layer = jnp.asarray(layer, jnp.int32)
+    if hw.backend == "pallas" or bool(interpret):
+        if k_minor is None:
+            k_minor = stored_k_minor(w.shape, w.dtype)
+        fn = _layer_gemm_callable(x2.shape[0], kdim, w3.shape[2], k_minor,
+                                  str(jnp.dtype(x2.dtype)), hw.name,
+                                  bool(interp))
+        y = fn(layer, x2, jnp.swapaxes(w3, 1, 2) if k_minor else w3)
+    else:
+        y = _xla_matmul_f32(x2, jax.lax.dynamic_index_in_dim(
+            w3, layer, keepdims=False), False)
+    out_dtype = jnp.dtype(out_dtype or x.dtype)
+    return y.astype(out_dtype).reshape(x.shape[:-1] + out_tail)
+
+
 def _ambient_mesh():
     """The multi-device mesh of an enclosing ``with mesh:`` block, outside
     any ``shard_map`` body.  The SPMD partitioner cannot split a compiled
@@ -424,7 +597,21 @@ def matmul(x: jax.Array, w: jax.Array, *, transpose_b: bool = False,
     compiled kernel called inside a multi-device ``with mesh:`` block
     takes that path on the enclosing mesh (rows over its first axis,
     columns over its second).
+
+    A ``LayerSlab`` weight is read in place through ``layer_matmul``; only
+    the mesh and transposed paths, which that entry lacks, slice the layer
+    out first.
     """
+    if isinstance(w, LayerSlab):
+        hw, interp = _resolve(hardware, interpret)
+        if (mesh is None and not transpose_b
+                and not (hw.backend == "pallas" and not interp
+                         and _ambient_mesh() is not None)):
+            return layer_matmul(x, w.stacked, w.layer, out_dtype=out_dtype,
+                                k_minor=w.k_minor, interpret=interpret,
+                                hardware=hardware)
+        w = w.sliced()
+    _count_gemm("whole")
     kdim = x.shape[-1]
     if transpose_b:
         if w.shape[-1] != kdim:

@@ -9,7 +9,10 @@ its kind within the period (mamba mixers, attention mixers, or all layers
 for the norms and MLPs), and one scan step runs one period in its
 published order.  Bodies are rematerialized (``jax.checkpoint``) and
 layer-boundary activations carry a sequence-parallel sharding constraint
-so saved activations shard over the "model" axis too.
+so saved activations shard over the "model" axis too.  The ssm and
+``mamba_hybrid`` decode bodies scan over layer indices instead of
+sliced layers: each GEMM reads its layer of the stacked weight leaf in
+place (``ops.layer_slab``), so a decode launch copies no weights.
 
 Entry points (used by train/serve steps and the dry-run):
 
@@ -28,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
+from repro.kernels import ops
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
@@ -47,8 +51,29 @@ def _stack(n: int) -> tuple[tuple[int, str], ...]:
 
 def _scan(cfg: ArchConfig, f, init, xs):
     """lax.scan honoring cfg.scan_unroll (the dry-run cost extraction
-    unrolls bodies so XLA cost_analysis counts every layer)."""
-    return jax.lax.scan(f, init, xs, unroll=bool(cfg.scan_unroll))
+    unrolls bodies so XLA cost_analysis counts every layer).  A GEMM in
+    the body counts once a step for ``ops.count_gemm_reads``."""
+    with ops.gemm_repeats(jax.tree.leaves(xs)[0].shape[0]):
+        return jax.lax.scan(f, init, xs, unroll=bool(cfg.scan_unroll))
+
+
+#: the leaves a decode body hands to its GEMMs whole (``ops.layer_slab``):
+#: Mamba's projections, attention's q/k/v/o, the MLP's two
+_GEMM_WEIGHTS = frozenset({"w_in", "w_out", "wq", "wk", "wv", "wo", "wi"})
+
+
+def _decode_layer(tree: dict, j, lead: int = 1) -> dict:
+    """Layer ``j`` of a stacked parameter subtree, its ``lead`` leading
+    dims flattened into one layer axis, for a decode body: each GEMM
+    weight stays the whole stacked leaf, read in place at layer ``j``
+    (``ops.layer_slab``); the small leaves (norm scales, conv weights,
+    ``A_log``, ``D``, ``dt_bias``, biases) are indexed."""
+    def pick(path, t):
+        if path[-1].key in _GEMM_WEIGHTS:
+            return ops.layer_slab(t, j, lead)
+        t = t.reshape((-1,) + t.shape[lead:])
+        return jax.lax.dynamic_index_in_dim(t, j, keepdims=False)
+    return jax.tree_util.tree_map_with_path(pick, tree)
 
 
 def init_lm(cfg: ArchConfig, key: jax.Array) -> tuple[dict, dict]:
@@ -188,25 +213,26 @@ def _mamba_hybrid_counts(cfg: ArchConfig) -> tuple[int, int, int]:
     return cfg.n_layers // len(pat), len(pat) - n_attn, n_attn
 
 
-def _mamba_hybrid_period(lp: dict, x: jax.Array, cfg: ArchConfig, mixer
+def _mamba_hybrid_period(layer, x: jax.Array, cfg: ArchConfig, mixer
                          ) -> jax.Array:
     """One period of the Mamba-2/attention hybrid in its published order:
     each layer is ``x + r * mixer(norm(x))`` then ``x + r * mlp(norm(x))``,
-    ``r`` the residual multiplier.  ``mixer(kind, i, p, h)`` runs the
+    ``r`` the residual multiplier.  ``layer(name, i)`` gives the
+    parameters of the period's ``i``-th entry under ``name`` (``ln1``,
+    ``ln2``, ``mlp`` or a mixer kind).  ``mixer(kind, i, p, h)`` runs the
     period's ``i``-th mixer of that kind (``p`` its parameters) on ``h``
     and keeps its cache itself."""
     r = cfg.residual_multiplier
-    at = lambda tree, i: jax.tree.map(lambda t: t[i], tree)
     count = {"mamba": 0, "attn": 0}
     for i, kind in enumerate(cfg.layer_pattern):
         j = count[kind]
         with jax.named_scope(kind):
-            h = apply_norm(at(lp["ln1"], i), x, cfg)
-            x = x + mixer(kind, j, at(lp[kind], j), h) * r
+            h = apply_norm(layer("ln1", i), x, cfg)
+            x = x + mixer(kind, j, layer(kind, j), h) * r
         count[kind] += 1
         with jax.named_scope("mlp"):
-            h = apply_norm(at(lp["ln2"], i), x, cfg)
-            x = x + apply_mlp(at(lp["mlp"], i), h, cfg) * r
+            h = apply_norm(layer("ln2", i), x, cfg)
+            x = x + apply_mlp(layer("mlp", i), h, cfg) * r
         x = constrain(x, "batch", "seq_sp", None)
     return x
 
@@ -305,7 +331,9 @@ def forward(params: dict, cfg: ArchConfig, tokens: jax.Array,
                                                   positions=positions))
                 caches[kind].append(c)
                 return out
-            xc = _mamba_hybrid_period(lp, xc, cfg, mixer)
+            xc = _mamba_hybrid_period(
+                lambda name, i: jax.tree.map(lambda t: t[i], lp[name]),
+                xc, cfg, mixer)
             return xc, {k: jax.tree.map(lambda *t: jnp.stack(t), *v)
                         for k, v in caches.items()}
         x, cache = _scan(cfg, body, x, params["layers"])
@@ -527,26 +555,33 @@ def decode_step(params: dict, cfg: ArchConfig, tokens: jax.Array,
         x, nm = _scan(cfg, body, x, (params["layers"], cache["moe"]))
         new_cache["moe"] = nm
     elif fam == "ssm":
+        # the scan runs over layer indices; the stacked weights stay loop
+        # invariants that each GEMM reads in place at its layer
         def body(xc, scan_in):
-            lp, c = scan_in
+            c, li = scan_in
+            lp = _decode_layer(params["layers"], li)
             h = apply_norm(lp["ln1"], xc, cfg)
             out, c = ssm_mod.decode_mamba2(lp["mixer"], h, c, cfg)
             return xc + out, c
-        x, nc = _scan(cfg, body, x, (params["layers"], cache["layers"]))
+        x, nc = _scan(cfg, body, x, (cache["layers"],
+                                     jnp.arange(cfg.n_layers)))
         new_cache = {"layers": nc}
     elif fam == "mamba_hybrid":
-        # the whole cache rides the scan's carry and each layer updates its
-        # own index in place: no per-period stack of new states
+        # the scan runs over period indices: the stacked weights stay loop
+        # invariants, each GEMM reading its layer ``g * n + i`` in place;
+        # the whole cache rides the carry and each layer updates its own
+        # index in place: no per-period stack of new states
         periods, n_mamba, n_attn = _mamba_hybrid_counts(cfg)
-        n_kind = {"mamba": n_mamba, "attn": n_attn}
+        per = {"mamba": n_mamba, "attn": n_attn}
+        per.update(dict.fromkeys(("ln1", "ln2", "mlp"),
+                                 len(cfg.layer_pattern)))
 
-        def body(carry, scan_in):
+        def body(carry, g):
             xc, cc = carry
-            lp, g = scan_in
             cc = dict(cc)
 
             def mixer(kind, i, p, h):
-                j = g * n_kind[kind] + i
+                j = g * per[kind] + i
                 c = jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(
                     t, j, keepdims=False), cc[kind])
                 out, c = (ssm_mod.decode_mamba2(p, h, c, cfg)
@@ -556,9 +591,13 @@ def decode_step(params: dict, cfg: ArchConfig, tokens: jax.Array,
                     lambda t, u: jax.lax.dynamic_update_index_in_dim(
                         t, u.astype(t.dtype), j, 0), cc[kind], c)
                 return out
-            return (_mamba_hybrid_period(lp, xc, cfg, mixer), cc), None
+
+            def layer(name, i):
+                return _decode_layer(params["layers"][name],
+                                     g * per[name] + i, lead=2)
+            return (_mamba_hybrid_period(layer, xc, cfg, mixer), cc), None
         (x, new_cache), _ = _scan(cfg, body, (x, dict(cache)),
-                                  (params["layers"], jnp.arange(periods)))
+                                  jnp.arange(periods))
     elif fam == "hybrid":
         pat = cfg.layer_pattern
 

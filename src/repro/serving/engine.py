@@ -177,6 +177,11 @@ class ServeEngine:
         #: counts once however many slots it covers) — the denominator of
         #: the benchmark's ``engine.tokens_per_launch``
         self.kernel_calls = 0
+        #: ``{"in_place": n, "whole": m}`` for the decode executable last
+        #: built: per decode step, the model GEMMs that read a stacked
+        #: weight in place at their layer and those handed a whole weight
+        #: (``ops.count_gemm_reads``, counted as the step traces)
+        self.decode_gemm_reads: Optional[dict] = None
         self._waiting: list[Request] = []
         self._slots: list[_Slot] = []
         self._done: dict[int, Request] = {}
@@ -479,15 +484,24 @@ class ServeEngine:
             self._prefill_fns[len(tokens)] = fn
         return fn(self.params, jnp.asarray([tokens], jnp.int32))
 
+    def _decode_traced(self, step, *args, **kwargs):
+        """Run a decode step's body as its executable traces, recording
+        ``decode_gemm_reads``."""
+        with ops.count_gemm_reads() as reads:
+            out = step(*args, **kwargs)
+        self.decode_gemm_reads = reads
+        return out
+
     def _paged_decode_fn(self, table: tuple):
         """The jitted paged decode step for one page table — THE derived
         ``windowed_decode`` kernel reading through the table's psi view."""
         fn = self._decode_fns.get(table)
         if fn is None:
             def engine_decode_paged(params, toks, poss, pools, _table=table):
-                return transformer.decode_step_paged(
-                    params, self.cfg, toks, poss, pools, page_table=_table,
-                    page=self.page, interpret=self.interpret)
+                return self._decode_traced(
+                    transformer.decode_step_paged, params, self.cfg, toks,
+                    poss, pools, page_table=_table, page=self.page,
+                    interpret=self.interpret)
             fn = functools.partial(jax.jit(engine_decode_paged), self.params)
             self._decode_fns[table] = fn
         return fn
@@ -501,9 +515,9 @@ class ServeEngine:
         if fn is None:
             def engine_decode_batched(params, toks, poss, pools,
                                       _tables=tables):
-                logits, pools = transformer.decode_step_paged_batched(
-                    params, self.cfg, toks, poss, pools,
-                    page_tables=_tables, page=self.page,
+                logits, pools = self._decode_traced(
+                    transformer.decode_step_paged_batched, params, self.cfg,
+                    toks, poss, pools, page_tables=_tables, page=self.page,
                     interpret=self.interpret)
                 return jnp.argmax(logits, axis=-1), pools
             fn = functools.partial(jax.jit(engine_decode_batched),
@@ -520,8 +534,8 @@ class ServeEngine:
         fn = self._decode_fns.get(())
         if fn is None:
             def engine_decode(params, toks, poss, cache):
-                logits, cache = registry.decode_step(params, self.cfg, toks,
-                                                     poss, cache)
+                logits, cache = self._decode_traced(
+                    registry.decode_step, params, self.cfg, toks, poss, cache)
                 return jnp.argmax(logits, axis=-1), cache
             fn = functools.partial(jax.jit(engine_decode), self.params)
             self._decode_fns[()] = fn

@@ -12,7 +12,9 @@ imports this file.
 """
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -21,8 +23,10 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.core.hardware import use_hardware
 from repro.kernels import ops
+from repro.models import registry, transformer
 
 
 @pytest.fixture(scope="module")
@@ -156,3 +160,73 @@ def test_matmul_under_mesh_compiles(topo):
         text = _compiled_text(
             lambda a, b: ops.matmul(a, b, out_dtype=jnp.float32), x, w)
     assert "tpu_custom_call" in text
+
+
+#: what a decode launch must not stage in HBM: a slice, copy or pad of a
+#: layer GEMM weight (one layer's, one period's for the hybrid, or the
+#: whole leaf relaid out, in either orientation of each layer).  A
+#: result in memory space 1 (VMEM, ``S(1)`` in its layout) is XLA
+#: prefetching a small leaf whole into the chip's scratchpad, not staging.
+_STAGING = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\](\S*) (\S+?)\(")
+
+
+def _weight_shapes(params: dict, lead: int) -> set:
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params["layers"]):
+        if path[-1].key not in transformer._GEMM_WEIGHTS:
+            continue
+        per_layer = leaf.shape[lead:]
+        k, n = per_layer[0], math.prod(per_layer[1:])
+        layers = (math.prod(leaf.shape[:lead]),)
+        for view in (per_layer, (1,) + per_layer, leaf.shape[1:],
+                     (1,) + leaf.shape[1:], leaf.shape, layers + per_layer,
+                     (k, n), (n, k), layers + (k, n), layers + (n, k)):
+            shapes.add(tuple(view))
+    return shapes
+
+
+def _staged_weights(text: str, weights: set) -> list:
+    found = []
+    for line in text.splitlines():
+        hit = _STAGING.match(line)
+        if not hit or not hit.group(2):
+            continue
+        name, shape, layout, op = hit.groups()
+        shape = tuple(int(d) for d in shape.split(","))
+        staging = any(s in name or s == op
+                      for s in ("copy", "pad", "slice", "dynamic-slice"))
+        if staging and shape in weights and "S(1)" not in layout:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("arch,n_layers,batch,lead", [
+    ("granite-4.0-h-micro", 20, 16, 2),     # two periods of ten
+    ("mamba2-780m", 2, 64, 1),
+])
+def test_decode_step_reads_weights_in_place(topo, one_chip, arch, n_layers,
+                                            batch, lead):
+    """The decode step at published widths (cut in depth only): every
+    layer GEMM reads its stacked leaf in place, so the compiled module
+    holds no slice, copy or pad of a weight's per-layer or per-period
+    shape, and the hybrid's B = 16 step stages no weights in its
+    temporaries (1.58 GB when each period's weights were sliced out)."""
+    cfg = get_config(arch).with_(n_layers=n_layers)
+    params = jax.eval_shape(lambda k: registry.init(cfg, k)[0],
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: transformer.init_cache(cfg, batch, 256))
+    on_chip = lambda t: _sds(one_chip, t.shape, t.dtype)
+    params, cache = jax.tree.map(on_chip, (params, cache))
+    toks = _sds(one_chip, (batch,), jnp.int32)
+    # the weights' stored layouts are the described chip's defaults
+    with jax.default_device(topo.devices[0]), ops.count_gemm_reads() as reads:
+        lowered = jax.jit(lambda p, t, c: transformer.decode_step(
+            p, cfg, t, t, c)).lower(params, toks, cache)
+    assert reads["whole"] == 1 and reads["in_place"] > 0
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _staged_weights(text, _weight_shapes(params, lead)) == []
+    if batch == 16:
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.58e9
